@@ -6,8 +6,8 @@ Three jobs in one module:
 
 * run the :mod:`repro.kernels.tune` sweep over a representative kernel x
   shape grid (deterministic proxy scoring in interpret mode, measured wall
-  time where ``REPRO_PALLAS_COMPILED=1`` actually lowers) and print the
-  chosen blocks per shape;
+  time on a TPU, where the kernels compile) and print the chosen blocks
+  per shape;
 * per tuned shape, record the jnp-ref wall time (the CPU-visible
   throughput proxy — NEVER gated), the interpret-mode correctness of the
   Pallas kernel vs its jnp oracle, and a crc32 digest of the kernel output

@@ -5,10 +5,3 @@ Approximation in Analog Resistive Crossbars for Recurrent Neural Networks".
 """
 
 __version__ = "1.0.0"
-
-# Bridge the newer-JAX mesh/shard_map API onto the pinned 0.4.x toolchain
-# before any repro module (or test subprocess) touches it.
-from repro.compat import install as _install_jax_compat
-
-_install_jax_compat()
-del _install_jax_compat

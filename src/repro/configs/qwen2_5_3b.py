@@ -18,6 +18,9 @@ CONFIG = ModelConfig(
     rope_theta=1_000_000.0,
     tie_embeddings=True,
     analog=AnalogSpec(enabled=True, adc_bits=5, activation="silu"),
+    # 3.09B params: stored in float32 they and their bf16 copies do not fit
+    # one 16 GB v5e for decode
+    serve_params_dtype="bfloat16",
 )
 
 SMOKE = CONFIG.replace(

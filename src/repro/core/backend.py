@@ -12,8 +12,8 @@ the whole config grid runs on either implementation:
   one elementwise pass, decode attention dequantizes int8 KV per-tile, the
   MoE gate einsum is the fused matmul vmapped over experts, and the
   non-int8 cached-attention path (bucketed prefill + decode) is one Pallas
-  pass per batch row.  Off-TPU the kernels execute in interpret mode (see
-  ``repro.kernels.interpret_mode``; ``REPRO_PALLAS_COMPILED=1`` drops it).
+  pass per batch row.  The kernels run compiled on a TPU and in interpret
+  mode elsewhere (``repro.kernels.interpret_mode``).
   Block sizes resolve per kernel x shape through the
   :mod:`repro.kernels.tune` cache at trace time, defaulting bitwise to the
   historical ``DEFAULT_BLOCKS`` on a cache miss.
@@ -23,8 +23,8 @@ whose backward re-derives the reference path's straight-through gradients
 with plain jnp ops (the STE formula itself is shared:
 :func:`repro.core.nladc.nladc_ste`), so Alg. 1 training works identically
 on both backends.  The backwards are hand-written rather than
-``jax.vjp``-of-ref because nesting the ref path's custom_vjp inside
-another custom_vjp's bwd breaks under scan transposition on jax 0.4.x.
+``jax.vjp``-of-ref because the ref path is itself a custom_vjp, and
+nesting one inside another's bwd does not survive scan transposition.
 
 Selection: ``AnalogConfig.backend`` (empty string = auto), the
 ``REPRO_ANALOG_BACKEND`` env var, or the ``--backend`` train/serve CLI flag.

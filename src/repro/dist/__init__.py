@@ -11,6 +11,5 @@ Everything mesh-, collective-, and partitioning-related lives here:
   all-reduce + error-feedback compression;
 * :mod:`repro.dist.ep`          — shard_map all-to-all expert-parallel MoE.
 
-Import side effects are limited to the jax-API compat install performed by
-``repro/__init__``; no module here touches device state at import time.
+No module here touches device state at import time.
 """

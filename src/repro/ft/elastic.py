@@ -72,6 +72,19 @@ def reshard(tree, mesh: Mesh, *, replicate_all: bool = False):
     return jax.tree.map(jax.device_put, tree, shardings)
 
 
+def init_sharded(init, key, mesh: Mesh, *, replicate_all: bool = False):
+    """``reshard(init(key), mesh)`` without the whole tree on one device.
+
+    ``init`` runs under ``jit`` with the standard layout as its output
+    sharding, so each device makes only its own shards; run eagerly (or
+    jitted without shardings) it would build every parameter on the first
+    device before the copy out.  The values are the same either way.
+    """
+    shapes = jax.eval_shape(init, key)
+    specs = SH.param_specs(shapes, mesh, replicate_all=replicate_all)
+    return jax.jit(init, out_shardings=SH.shardings_for(specs, mesh))(key)
+
+
 def plan_request_rebalance(displaced, loads: Dict[str, int]
                            ) -> Dict[str, list]:
     """Assign displaced serving requests to surviving chips, least-loaded

@@ -7,25 +7,21 @@ not.  The jnp oracles stay available as ``repro.kernels.ref`` (they are the
 correctness contract for every kernel and the backward rule of the
 ``"pallas"`` analog backend, see :mod:`repro.core.backend`).
 
-Kernels execute in Pallas interpret mode off-TPU (``interpret_mode()``;
-force with ``REPRO_PALLAS_INTERPRET=0/1``, or ``REPRO_PALLAS_COMPILED=1``
-to drop interpret mode entirely on platforms with real Pallas lowering).
+Kernels run compiled on a TPU and in Pallas interpret mode everywhere
+else (``interpret_mode()`` decides from the platform alone).
 Block sizes resolve through the :mod:`repro.kernels.tune` cache
 (``REPRO_KERNEL_CACHE`` / ``--kernel-blocks``), falling back to each
 kernel's ``DEFAULT_BLOCKS``.
 """
 
 from repro.kernels import ref, tune
-from repro.kernels.ops import (analog_tile, compiled_requested,
-                               compiled_supported, flash_decode_int8,
+from repro.kernels.ops import (analog_tile, flash_decode_int8,
                                fused_matmul_nladc, interpret_mode,
                                lstm_gates, moe_fused_matmul, nladc,
                                prefill_attention)
 
 __all__ = [
     "analog_tile",
-    "compiled_requested",
-    "compiled_supported",
     "flash_decode_int8",
     "fused_matmul_nladc",
     "interpret_mode",

@@ -6,10 +6,12 @@ runs at the column periphery; on TPU the ramp quantizer runs on the matmul
 accumulator **while it is still in VMEM**, so the activation adds zero HBM
 round-trips (vs. matmul -> write 16 GB/s-bound activations -> read -> act).
 
-Grid (i, j, k) over (M/bm, N/bn, K/bk); the f32 accumulator tile persists in
-the output ref across the k-steps (revisiting pattern); the NL-ADC epilogue
-(thermometer compare + affine decode + optional bias) fires on the last
-k-step.  Block shapes default to MXU-aligned (128, 128, 512).
+Grid (i, j, k) over (M/bm, N/bn, K/bk); the f32 accumulator tile lives in
+VMEM scratch across the k-steps; the NL-ADC epilogue (thermometer compare
++ affine decode + optional bias) fires on the last k-step and writes the
+only output.  Every operand block is 2-D (or an untiled (P,) table) so the
+TPU tiling accepts it: the bias is a (1, bn) row of a (1, N) array, and
+the fast-path bank rows a (1, P) slice of an (n_blocks, 1, P) table.
 """
 
 from __future__ import annotations
@@ -20,6 +22,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.nladc import Ramp
 from repro.kernels import tune
@@ -30,8 +33,12 @@ from repro.kernels.ref import (closed_form_decode, decode_mode, decode_params,
 DEFAULT_BLOCKS = (256, 256, 512)   # (bm, bn, bk)
 
 
-def _kernel(x_ref, w_ref, thr_ref, b_ref, acc_ref, o_ref, *,
-            n_k: int, y0, lsb_l, lsb_r, m, mode, has_bias, bank_fast):
+def _kernel(*refs, n_k: int, y0, lsb_l, lsb_r, m, mode, has_bias,
+            bank_fast):
+    if has_bias:
+        x_ref, w_ref, thr_ref, b_ref, o_ref, acc_ref = refs
+    else:
+        x_ref, w_ref, thr_ref, o_ref, acc_ref = refs
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -46,7 +53,7 @@ def _kernel(x_ref, w_ref, thr_ref, b_ref, acc_ref, o_ref, *,
     def _epilogue():
         acc = acc_ref[...]
         if has_bias:
-            acc = acc + b_ref[...].astype(jnp.float32)
+            acc = acc + b_ref[...].astype(jnp.float32)   # (1, bn) row
         # thr: (P,) shared ramp, (bn, P) per-column (threshold banks), or —
         # fast path — the block's single (1, P) bank row, register-resident
         # through the broadcast compare
@@ -87,7 +94,9 @@ def fused_matmul_nladc_pallas(
             raise ValueError(
                 f"BlockRowThresholds has {thr.shape[0]} rows for "
                 f"{grid[1]} lane blocks (bn={bn})")
-        thr_spec = pl.BlockSpec((1, thr.shape[1]), lambda i, j, k: (j, 0))
+        thr = thr[:, None, :]                        # (n_blocks, 1, P)
+        thr_spec = pl.BlockSpec((None, 1, thr.shape[2]),
+                                lambda i, j, k: (j, 0, 0))
     else:
         thr = jnp.asarray(ramp.thresholds, jnp.float32) \
             if thresholds is None else thresholds.astype(jnp.float32)
@@ -97,27 +106,24 @@ def fused_matmul_nladc_pallas(
         else:
             thr_spec = pl.BlockSpec((thr.shape[0],), lambda i, j, k: (0,))
     has_bias = bias is not None
-    if bias is None:
-        bias = jnp.zeros((n_dim,), jnp.float32)
+    in_specs = [
+        pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
+        pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
+        thr_spec,
+    ]
+    operands = [x, w, thr]
+    if has_bias:
+        in_specs.append(pl.BlockSpec((1, bn), lambda i, j, k: (0, j)))
+        operands.append(bias.reshape(1, n_dim))
     kernel = functools.partial(
         _kernel, n_k=grid[2], y0=y0, lsb_l=lsb_l, lsb_r=lsb_r, m=mm,
         mode=decode_mode(ramp), has_bias=has_bias, bank_fast=bank_fast)
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            thr_spec,
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),  # acc (f32)
-            pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),  # quantized out
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((m_dim, n_dim), jnp.float32),
-            jax.ShapeDtypeStruct((m_dim, n_dim), x.dtype),
-        ],
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((m_dim, n_dim), x.dtype),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(x, w, thr, bias)[1]
+    )(*operands)

@@ -38,7 +38,8 @@ def _nladc_kernel(x_ref, thr_ref, o_ref, *, y0, lsb_l, lsb_r, m, mode,
     x = x_ref[...].astype(jnp.float32)
     # thr: (P,) shared ramp in VMEM, (bn, P) per-column (banked layout,
     # the column->bank gather resolved at trace time by ops.nladc), or —
-    # fast path — the block's single (1, P) bank row.
+    # fast path — the block's single (1, P) bank row of an (n_blocks, 1, P)
+    # table (a (1, P) block of an (n_blocks, P) array breaks the tiling).
     thr = thr_ref[0] if bank_fast else thr_ref[...]
     n = thermometer_count(x, thr)
     y = closed_form_decode(n, mode, y0, lsb_l, lsb_r, m)
@@ -78,7 +79,9 @@ def nladc_pallas(x, ramp: Ramp, *, thresholds=None,
             raise ValueError(
                 f"BlockRowThresholds has {thr.shape[0]} rows for "
                 f"{grid[1]} lane blocks (bn={bn})")
-        thr_spec = pl.BlockSpec((1, thr.shape[1]), lambda i, j: (j, 0))
+        thr = thr[:, None, :]                        # (n_blocks, 1, P)
+        thr_spec = pl.BlockSpec((None, 1, thr.shape[2]),
+                                lambda i, j: (j, 0, 0))
     else:
         thr = jnp.asarray(ramp.thresholds, jnp.float32) \
             if thresholds is None else thresholds.astype(jnp.float32)

@@ -1,14 +1,12 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-Handles: leading-dim flattening, padding to block multiples, the
-interpret-mode switch (TPU target, CPU container: ``interpret=True``
-executes the kernel bodies in Python for correctness validation), and
-straight-through-estimator gradients matching :mod:`repro.core.nladc`.
+Handles: leading-dim flattening, padding to block multiples, and the
+interpret-mode switch (compiled on a TPU; on the CPU ``interpret=True``
+executes the kernel bodies with XLA ops for correctness validation).
 """
 
 from __future__ import annotations
 
-import functools
 import os
 from typing import Optional
 
@@ -28,64 +26,13 @@ from repro.kernels import tune
 from repro.kernels.common import BlockRowThresholds
 
 
-def compiled_requested() -> bool:
-    """``REPRO_PALLAS_COMPILED=1``: drop ``interpret=True`` everywhere.
-
-    The escape hatch that makes the parity suite (and the autotune sweep)
-    runnable in compiled mode on platforms with real Pallas lowering.
-    Takes precedence over ``REPRO_PALLAS_INTERPRET`` — it is the explicit
-    opt-in, while the interpret env is exported wholesale by CI legs.
-    """
-    return os.environ.get("REPRO_PALLAS_COMPILED", "") \
-        not in ("", "0", "false", "False")
-
-
 def interpret_mode() -> bool:
-    """True when the kernels should run in Pallas interpret mode.
+    """True when the kernels run in Pallas interpret mode.
 
-    ``REPRO_PALLAS_COMPILED=1`` forces compiled; else
-    ``REPRO_PALLAS_INTERPRET`` forces it either way; default: interpret
-    everywhere except a real TPU backend.
+    Decided by the platform alone: compiled on a TPU, interpreted
+    everywhere else (the CPU test path).
     """
-    if compiled_requested():
-        return False
-    env = os.environ.get("REPRO_PALLAS_INTERPRET", "")
-    if env:  # empty string == unset (CI matrix legs export "")
-        return env not in ("0", "false", "False")
     return jax.default_backend() != "tpu"
-
-
-_interpret = interpret_mode  # backward-compat alias
-
-_COMPILED_PROBE = None
-
-
-def compiled_supported():
-    """(ok, reason): can this platform lower a compiled Pallas call?
-
-    Probes once with a tiny ``interpret=False`` kernel.  On CPU jax 0.4.x
-    raises ``Only interpret mode is supported on CPU backend`` — the
-    reason string lets callers (the parity suite, the tune harness) skip
-    cleanly instead of erroring mid-sweep.
-    """
-    global _COMPILED_PROBE
-    if _COMPILED_PROBE is None:
-        from jax.experimental import pallas as pl
-
-        def _k(x_ref, o_ref):
-            o_ref[...] = x_ref[...] + 1.0
-
-        try:
-            out = pl.pallas_call(
-                _k, out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
-                interpret=False)(jnp.zeros((8, 128), jnp.float32))
-            jax.block_until_ready(out)
-            _COMPILED_PROBE = (True, "")
-        except Exception as e:  # noqa: BLE001 — any lowering failure
-            _COMPILED_PROBE = (
-                False, f"no compiled Pallas lowering on "
-                f"{jax.default_backend()}: {type(e).__name__}: {e}")
-    return _COMPILED_PROBE
 
 
 def _pad_to(x, mult, axis):

@@ -22,14 +22,14 @@ seam that makes the choice data-driven without touching kernel code:
       3. the kernel's ``DEFAULT_BLOCKS`` (bitwise exactly the pre-tune
          behaviour — a cache miss can never change numerics).
 
-* ``autotune`` — the sweep harness.  Where Pallas can compile
-  (``REPRO_PALLAS_COMPILED=1`` on a TPU host) each candidate is timed and
-  the fastest wins; in interpret mode (CI) candidates are ranked by a
-  deterministic static cost model (padding waste x grid overhead x VMEM
-  fit) so the sweep is exercisable everywhere and the cache file it writes
-  is byte-deterministic.  The jnp-ref wall time is measured once per shape
-  as the recorded throughput proxy (it goes to ``BENCH_kernels.json``, not
-  into the selection).
+* ``autotune`` — the sweep harness.  Where Pallas compiles (on a TPU)
+  each candidate is timed and the fastest wins; in interpret mode (CI)
+  candidates are ranked by a deterministic static cost model (padding
+  waste x grid overhead x VMEM fit) so the sweep is exercisable
+  everywhere and the cache file it writes is byte-deterministic.  The
+  jnp-ref wall time is measured once per shape as the recorded
+  throughput proxy (it goes to ``BENCH_kernels.json``, not into the
+  selection).
 
 Clamp accounting: kernel wrappers call :func:`warn_clamp` instead of
 silently shrinking a requested block to the operand — a one-time
@@ -449,9 +449,9 @@ def autotune_kernel(kernel: str, shape: Sequence[int], dtype=jnp.float32,
     """Sweep candidates for one kernel x shape and record the winner.
 
     ``measure``: ``"wall"`` times each candidate's compiled Pallas call
-    (requires a platform that can lower Pallas — see
-    ``REPRO_PALLAS_COMPILED``); ``"proxy"`` ranks by :func:`proxy_score`
-    (deterministic, the interpret-mode/CI default).  ``None`` auto-selects.
+    (requires a TPU, where the kernels compile); ``"proxy"`` ranks by
+    :func:`proxy_score` (deterministic, the interpret-mode/CI default).
+    ``None`` auto-selects.
     """
     from repro.kernels import ops
 

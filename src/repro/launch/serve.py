@@ -31,8 +31,9 @@ from repro import configs
 from repro.core.backend import backend_names
 from repro.core.device import device_names, resolve_device
 from repro.kernels import tune
+from repro.launch.compile_cache import enable_compile_cache
 from repro.nn.model import build
-from repro.serve.engine import Request, ServingEngine
+from repro.serve.engine import Request, ServingEngine, serving_params
 from repro.serve.lifecycle import RecalPolicy
 
 
@@ -142,6 +143,7 @@ def main():
                          "— overrides the tune cache (also: "
                          "REPRO_KERNEL_BLOCKS env)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     try:
         tune.configure(args.kernel_blocks, args.kernel_cache)
@@ -183,7 +185,7 @@ def main():
         _export_obs(args, obs)
         return
     model = build(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    params = serving_params(model, jax.random.PRNGKey(0))
     # Build-stage aging only composes with infer mode: exact mode would pair
     # aged weights with a pristine NL-ADC and no read noise — a chip that
     # cannot physically exist — so the driver gates it rather than the engine.

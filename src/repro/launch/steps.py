@@ -7,6 +7,7 @@ arrays (ShapeDtypeStruct-friendly).
 
 from __future__ import annotations
 
+import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -149,16 +150,29 @@ def make_dp_opt_state(optimizer: optim.Adam, params, mesh,
     (``{"opt": adam_state, "ef": residuals}``; residual leaves are stacked
     ``(n_replicas, *param_shape)`` f32, sharded over the data-like axes by
     the step's in_specs).  Every other mode returns plain Adam state.
+
+    The state is made in place on ``mesh``: each Adam moment takes its
+    param's layout (replicated where the param is not laid out on a mesh)
+    and the step count is replicated.  Zero-filled state depends on no
+    input's values, so a plain ``jit`` would put all of it on the first
+    device.
     """
-    opt_state = jax.jit(optimizer.init)(params)
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    replicated = NamedSharding(mesh, P())
+    moments = jax.tree.map(
+        lambda p: p.sharding if isinstance(p.sharding, NamedSharding)
+        else replicated, params)
+    opt_state = jax.jit(optimizer.init, out_shardings=optim.OptState(
+        count=replicated, mu=moments, nu=moments))(params)
     if grad_comm != "int8":
         return opt_state
-    n_rep = 1
-    for ax in ("pod", "data"):
-        if ax in mesh.axis_names:
-            n_rep *= mesh.shape[ax]
-    ef = jax.tree.map(
-        lambda p: jnp.zeros((n_rep,) + p.shape, jnp.float32), params)
+    axes = tuple(ax for ax in ("pod", "data") if ax in mesh.axis_names)
+    n_rep = math.prod(mesh.shape[ax] for ax in axes)
+    ef = jax.jit(
+        lambda ps: jax.tree.map(
+            lambda p: jnp.zeros((n_rep,) + p.shape, jnp.float32), ps),
+        out_shardings=NamedSharding(mesh, P(axes)))(params)
     return {"opt": opt_state, "ef": ef}
 
 

@@ -32,8 +32,9 @@ from repro.core.backend import backend_names
 from repro.core.device import device_names
 from repro.data.pipeline import SyntheticLM
 from repro.dist import sharding as SH
-from repro.ft.elastic import build_mesh, plan_for_devices, reshard
+from repro.ft.elastic import build_mesh, init_sharded, plan_for_devices
 from repro.kernels import tune
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.launch.steps import (make_dp_opt_state, make_dp_train_step,
                                 make_optimizer, make_train_step)
@@ -42,6 +43,57 @@ from repro.nn.model import build
 from repro.train.loop import TrainState, Trainer
 
 GRAD_COMM_MODES = ("gspmd", "psum", "hierarchical", "int8")
+
+
+def make_trainer(model, opt, mesh, grad_comm: str, data, *, seed: int = 0,
+                 ckpt_dir=None, log_every: int = 10):
+    """The training set-up on ``mesh``: the train step for ``grad_comm``,
+    params made in place with the megatron layout (all replicated for SSM
+    configs), optimizer state laid out like them, and batches placed by
+    ``batch_specs``.  ``data`` is the pipeline (``batch_at(step)``);
+    -> ``(Trainer, TrainState)``."""
+    cfg = model.cfg
+    if grad_comm == "gspmd":
+        train_step = make_train_step(model, opt)
+    else:
+        train_step = make_dp_train_step(model, opt, mesh,
+                                        grad_comm=grad_comm)
+    params = init_sharded(model.init, jax.random.PRNGKey(seed), mesh,
+                          replicate_all=cfg.family == "ssm")
+    # int8 grad-comm carries per-replica error-feedback residuals alongside
+    # the Adam state (see make_dp_opt_state); other modes get plain state.
+    opt_state = make_dp_opt_state(opt, params, mesh, grad_comm=grad_comm)
+    batch_sh = None
+
+    def put_batch(b):
+        nonlocal batch_sh
+        batch = {k: jnp.asarray(v) for k, v in b.items()}
+        n = batch["tokens"].shape[0]
+        if cfg.modality == "vision":
+            batch["patch_embeds"] = vision_patch_stub(
+                jax.random.PRNGKey(7), n, cfg.n_patches, cfg.d_model)
+        if cfg.modality == "audio":
+            batch["frames"] = audio_frame_stub(
+                jax.random.PRNGKey(7), n, cfg.enc_len, cfg.d_model)
+        if batch_sh is None:
+            batch_sh = SH.shardings_for(SH.batch_specs(batch, mesh), mesh)
+        return jax.tree.map(jax.device_put, batch, batch_sh)
+
+    trainer = Trainer(model, opt, train_step, data, ckpt_dir=ckpt_dir,
+                      put_batch=put_batch, log_every=log_every)
+    return trainer, TrainState(params, opt_state)
+
+
+def fit(trainer, state, n_steps: int, mesh, grad_comm: str):
+    """``trainer.fit`` under the mesh context its step needs.  GSPMD traces
+    under the mesh so mesh-aware model branches (sequence parallelism,
+    ``moe_impl="ep_shardmap"``) see it, same as dryrun's lowering; the
+    explicit-collective DP step must trace *outside* any mesh context (see
+    ``make_dp_train_step``)."""
+    ctx = (jax.set_mesh(mesh) if grad_comm == "gspmd"
+           else contextlib.nullcontext())
+    with ctx:
+        return trainer.fit(state, n_steps)
 
 
 def main():
@@ -80,6 +132,7 @@ def main():
                          "— overrides the tune cache (also: "
                          "REPRO_KERNEL_BLOCKS env)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     try:
         tune.configure(args.kernel_blocks, args.kernel_cache)
@@ -112,11 +165,9 @@ def main():
     model = build(cfg)
     opt = make_optimizer(cfg, total_steps=args.steps)
 
-    replicate = cfg.family == "ssm"
     if args.grad_comm == "gspmd":
         mesh = (make_production_mesh() if args.production_mesh
                 else make_host_mesh())
-        train_step = make_train_step(model, opt)
     else:
         # Explicit-collective DP: the elastic planner picks the largest
         # (data, model=1) mesh whose data axis divides the global batch.
@@ -128,43 +179,12 @@ def main():
             print(f"[train] note: data axis must divide --batch "
                   f"{args.batch}; using {used} of {len(jax.devices())} "
                   "devices")
-        train_step = make_dp_train_step(model, opt,
-                                        mesh, grad_comm=args.grad_comm)
 
-    key = jax.random.PRNGKey(0)
-    params = reshard(model.init(key), mesh, replicate_all=replicate)
-    # int8 grad-comm carries per-replica error-feedback residuals alongside
-    # the Adam state (see make_dp_opt_state); other modes get plain state.
-    opt_state = make_dp_opt_state(opt, params, mesh,
-                                  grad_comm=args.grad_comm)
-
-    pipeline = SyntheticLM(cfg.vocab, args.seq, args.batch)
-    batch_sh = None
-
-    def put_batch(b):
-        nonlocal batch_sh
-        batch = {k: jnp.asarray(v) for k, v in b.items()}
-        if cfg.modality == "vision":
-            batch["patch_embeds"] = vision_patch_stub(
-                jax.random.PRNGKey(7), args.batch, cfg.n_patches,
-                cfg.d_model)
-        if cfg.modality == "audio":
-            batch["frames"] = audio_frame_stub(
-                jax.random.PRNGKey(7), args.batch, cfg.enc_len, cfg.d_model)
-        if batch_sh is None:
-            batch_sh = SH.shardings_for(SH.batch_specs(batch, mesh), mesh)
-        return jax.tree.map(jax.device_put, batch, batch_sh)
-
-    trainer = Trainer(model, opt, train_step, pipeline,
-                      ckpt_dir=args.ckpt_dir, put_batch=put_batch)
-    # GSPMD: trace under the mesh so mesh-aware model branches (sequence
-    # parallelism, moe_impl="ep_shardmap") see it, same as dryrun's
-    # lowering.  The explicit-collective DP step must trace *outside* any
-    # mesh context (see make_dp_train_step).
-    mesh_ctx = (jax.set_mesh(mesh) if args.grad_comm == "gspmd"
-                else contextlib.nullcontext())
-    with mesh_ctx:
-        state = trainer.fit(TrainState(params, opt_state), args.steps)
+    trainer, state = make_trainer(
+        model, opt, mesh, args.grad_comm,
+        SyntheticLM(cfg.vocab, args.seq, args.batch), seed=0,
+        ckpt_dir=args.ckpt_dir)
+    fit(trainer, state, args.steps, mesh, args.grad_comm)
     print("[train] done; final loss:",
           trainer.history[-1]["loss"] if trainer.history else "n/a")
 

@@ -40,7 +40,7 @@ import os
 import zlib
 from typing import Dict, List, Optional
 
-from repro.serve.engine import Request, ServingEngine
+from repro.serve.engine import Request, ServingEngine, serving_params
 
 FLEET_SCHEMA = 1
 
@@ -245,7 +245,7 @@ class FleetEngine:
         those device presets; the rest inherit ``cfg.analog.device``.
         ``params`` (pristine, pre-aging) is shared — chips differ by their
         device draws, not their trained weights; default is
-        ``model.init(PRNGKey(0))`` built once.  The throughput knobs
+        ``serving_params(model, PRNGKey(0))`` built once.  The throughput knobs
         (``prefill`` / ``prefill_buckets`` / ``pack_prefill`` /
         ``detok_thread``) pass through to every chip's engine.
         """
@@ -311,7 +311,7 @@ class FleetEngine:
         model = build(chip_cfg)
         if params is None:
             import jax
-            params = model.init(jax.random.PRNGKey(0))
+            params = serving_params(model, jax.random.PRNGKey(0))
         engine = ServingEngine(
             model, params, max_batch=max_batch, max_len=max_len,
             device=dev, recal=recal,
@@ -622,7 +622,7 @@ class FleetEngine:
             model = build(chip_cfg)
             if params_like is None:
                 import jax
-                params_like = model.init(jax.random.PRNGKey(0))
+                params_like = serving_params(model, jax.random.PRNGKey(0))
             engine = ServingEngine.restore(
                 model, os.path.join(root, "chips", cid), step=step,
                 params_like=params_like, external_maintenance=True,

@@ -1,4 +1,4 @@
-"""Subprocesses with a forced XLA host-device count.
+"""CPU subprocesses with a forced XLA host-device count.
 
 A process's jax backend is initialized once, so anything that needs N fake
 CPU devices (multi-device tests, the dist-scaling benchmark) must run in a
@@ -22,8 +22,11 @@ _SRC = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def run_in_subprocess(code: str, devices: int = 8, *,
                       timeout: int = 420) -> subprocess.CompletedProcess:
     """Run ``code`` in a fresh interpreter with ``devices`` fake devices."""
+    # JAX_PLATFORMS=cpu: the children exist for fake host devices, and must
+    # never reach for an accelerator the parent process may hold
     env = dict(os.environ,
                XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
+               JAX_PLATFORMS="cpu",
                PYTHONPATH=_SRC)
     return subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                           capture_output=True, text=True, env=env,

@@ -21,16 +21,6 @@ from repro.core import backend as BK
 from repro.core.analog_layer import (AnalogActivation, AnalogConfig,
                                      analog_matmul_act, dense_nladc)
 from repro.core.nladc import NLADC, build_ramp
-from repro.kernels import ops as _ops
-
-# REPRO_PALLAS_COMPILED=1 drops interpret=True so this suite runs against
-# the compiled kernels on a TPU host; where Pallas cannot lower, skip the
-# whole module with the probe's reason instead of erroring mid-test.
-if _ops.compiled_requested():
-    _ok, _reason = _ops.compiled_supported()
-    if not _ok:
-        pytest.skip(f"REPRO_PALLAS_COMPILED=1 but {_reason}",
-                    allow_module_level=True)
 
 MODES = ["exact", "train", "infer"]
 BACKENDS = ["ref", "pallas"]

@@ -804,8 +804,6 @@ def test_engine_checkpoint_roundtrip_no_scheduler(tmp_path):
 # ---------------------------------------------------------------------------
 
 _RESTART_COMMON = """
-    import os
-    os.environ["REPRO_PALLAS_INTERPRET"] = "1"
     import json, zlib
     import numpy as np
     import jax, jax.numpy as jnp

@@ -305,3 +305,48 @@ def test_elastic_reshard_roundtrip_subprocess():
         print("RESHARD OK")
     """, devices=8)
     assert "RESHARD OK" in out
+
+
+def test_train_state_made_in_place_on_mesh_subprocess():
+    """Params and optimizer state are made directly in their layout on the
+    mesh, gspmd (megatron, model axis 4) and DP (data axis 4, with the int8
+    residual): nothing lands whole on the first device, and the params are
+    bitwise the single-device ``model.init``."""
+    out = _run_subprocess("""
+        import jax, numpy as np
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro import configs
+        from repro.configs.base import AnalogSpec
+        from repro.ft.elastic import build_mesh, init_sharded, plan_for_devices
+        from repro.launch.mesh import make_host_mesh
+        from repro.launch.steps import make_dp_opt_state, make_optimizer
+        from repro.nn.model import build
+
+        cfg = configs.get_smoke("qwen2.5-3b").replace(
+            analog=AnalogSpec(enabled=False))
+        model = build(cfg)
+        opt = make_optimizer(cfg)
+        key = jax.random.PRNGKey(0)
+        host = jax.tree.map(np.asarray, model.init(key))
+        dp = build_mesh(plan_for_devices(4, global_batch=8, model_parallel=1))
+        for mesh, comm in ((make_host_mesh(), "gspmd"), (dp, "psum"),
+                           (dp, "int8")):
+            params = init_sharded(model.init, key, mesh)
+            for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(host)):
+                np.testing.assert_array_equal(np.asarray(a), b)
+            state = make_dp_opt_state(opt, params, mesh, grad_comm=comm)
+            for leaf in jax.tree.leaves((params, state)):
+                assert len(leaf.sharding.device_set) == 4, (comm, leaf.shape)
+            adam = state["opt"] if comm == "int8" else state
+            for p, m in zip(jax.tree.leaves(params),
+                            jax.tree.leaves(adam.mu)):
+                assert m.sharding == p.sharding, (comm, m.sharding)
+            if comm == "gspmd":
+                assert any(p.sharding.spec != P()
+                           for p in jax.tree.leaves(params))
+            if comm == "int8":
+                for r in jax.tree.leaves(state["ef"]):
+                    assert r.sharding.spec == P(("data",)), r.sharding
+        print("IN PLACE OK")
+    """, devices=4)
+    assert "IN PLACE OK" in out

@@ -405,8 +405,6 @@ def test_fleet_restore_hints_engine_checkpoint(tmp_path):
 # ---------------------------------------------------------------------------
 
 _FLEET_COMMON = """
-    import os
-    os.environ["REPRO_PALLAS_INTERPRET"] = "1"
     import json
     import numpy as np
     import jax

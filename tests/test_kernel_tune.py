@@ -235,18 +235,3 @@ def test_autotune_records_clamped_candidates():
     entry = cache.entries[tune.cache_key("nladc", (8, 24))]
     assert tuple(entry["blocks"]) == (8, 24)
     assert entry["clamped"]["applied"] == [8, 24]
-
-
-def test_compiled_escape_hatch(monkeypatch):
-    """REPRO_PALLAS_COMPILED=1 forces compiled mode; where the platform
-    cannot lower Pallas the probe reports a skippable reason."""
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    assert ops.interpret_mode()
-    monkeypatch.setenv("REPRO_PALLAS_COMPILED", "1")
-    assert not ops.interpret_mode()      # takes precedence
-    assert tune.backend_mode() == "compiled"
-    ok, reason = ops.compiled_supported()
-    if not ok:
-        assert reason            # non-empty, names the platform
-        pytest.skip(f"compiled Pallas unsupported here: {reason}")
-    # on a real TPU host the sweep would measure wall time from here on

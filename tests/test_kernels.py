@@ -7,14 +7,6 @@ import pytest
 from repro.core.nladc import build_ramp, nladc_reference
 from repro.kernels import ops, ref
 
-# compiled mode (REPRO_PALLAS_COMPILED=1): run against the real lowering
-# where the platform has one, skip cleanly where it does not
-if ops.compiled_requested():
-    _ok, _reason = ops.compiled_supported()
-    if not _ok:
-        pytest.skip(f"REPRO_PALLAS_COMPILED=1 but {_reason}",
-                    allow_module_level=True)
-
 SHAPES_2D = [(8, 8), (70, 130), (256, 512), (257, 513), (1, 640)]
 ACTS = ["sigmoid", "tanh", "softplus", "elu", "selu", "gelu", "swish"]
 

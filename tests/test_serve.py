@@ -8,7 +8,7 @@ import pytest
 from repro import configs
 from repro.configs.base import AnalogSpec
 from repro.nn.model import build
-from repro.serve.engine import Request, ServingEngine
+from repro.serve.engine import Request, ServingEngine, serving_params
 
 
 @pytest.fixture(scope="module")
@@ -54,6 +54,51 @@ def test_continuous_batching_slot_reuse(smoke_model):
         if not engine.queue and all(engine.slot_free):
             break
     assert len(r1.generated) == 2 and len(r2.generated) == 2
+
+
+def test_engine_emits_only_vocabulary_ids():
+    """The embedding table is padded to vocab_pad_multiple; those padded
+    logit columns are not tokens, even where they score highest."""
+    cfg = configs.get_smoke("qwen2.5-3b").replace(
+        vocab=250, dtype="float32", analog=AnalogSpec(enabled=False))
+    assert cfg.padded_vocab > cfg.vocab
+    model = build(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    decode_step = model.decode_step
+
+    def padding_wins(params, state, tokens, *, key=None):
+        logits, state = decode_step(params, state, tokens, key=key)
+        return logits.at[..., cfg.vocab:].set(1e9), state
+
+    model.decode_step = padding_wins
+    engine = ServingEngine(model, params, max_batch=2, max_len=32)
+    rng = np.random.default_rng(2)
+    reqs = [Request(uid=u, prompt=rng.integers(0, cfg.vocab, 5)
+                    .astype(np.int32), max_new_tokens=3) for u in range(2)]
+    for r in reqs:
+        engine.submit(r)
+    engine.run_to_completion()
+    toks = [t for r in reqs for t in r.generated]
+    assert len(toks) == 6
+    assert all(0 <= t < cfg.vocab for t in toks)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_serving_params_are_init_in_serve_dtype(dtype):
+    """The serve path's params are ``model.init``'s values with the
+    float32 leaves stored in ``serve_params_dtype``."""
+    cfg = configs.get_smoke("qwen2.5-3b").replace(serve_params_dtype=dtype)
+    model = build(cfg)
+    key = jax.random.PRNGKey(3)
+    want = jax.tree.map(
+        lambda a: a.astype(dtype) if a.dtype == jnp.float32 else a,
+        model.init(key))
+    got = serving_params(model, key)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(w, np.float32))
 
 
 def test_engine_greedy_matches_manual(smoke_model):

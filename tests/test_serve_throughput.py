@@ -4,8 +4,8 @@ prefill, and the background detokenize pipeline.
 The correctness anchor for every knob is **bitwise parity** with the
 legacy scan-prefill path: identical token streams AND identical final
 decode caches, noiseless and noisy.  Under the CI pallas job
-(``REPRO_ANALOG_BACKEND=pallas REPRO_PALLAS_INTERPRET=1``) the same
-assertions run against the kernel backend.
+(``REPRO_ANALOG_BACKEND=pallas``, kernels in interpret mode on the CPU)
+the same assertions run against the kernel backend.
 """
 
 import jax

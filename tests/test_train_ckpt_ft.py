@@ -19,6 +19,39 @@ from repro.train import optim
 from repro.train.loop import TrainState, Trainer
 
 
+@pytest.mark.parametrize("grad_comm", ["gspmd", "psum"])
+def test_make_trainer_matches_plain_loop(grad_comm):
+    """``launch.train``'s set-up on a one-device mesh takes the same steps
+    as a plain jitted step on ``model.init`` params."""
+    from jax.sharding import Mesh
+
+    from repro.launch.steps import make_train_step
+    from repro.launch.train import fit, make_trainer
+    from repro.nn.model import build
+
+    cfg = configs.get_smoke("qwen2.5-3b")
+    model = build(cfg)
+    opt = make_optimizer(cfg, total_steps=3)
+    mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1),
+                ("data", "model"))
+    trainer, state = make_trainer(
+        model, opt, mesh, grad_comm,
+        SyntheticLM(cfg.vocab, 16, 4, seed=1), seed=2, log_every=1)
+    fit(trainer, state, 3, mesh, grad_comm)
+    got = [h["loss"] for h in trainer.history]
+
+    params = model.init(jax.random.PRNGKey(2))
+    plain = Trainer(model, opt, make_train_step(model, opt),
+                    SyntheticLM(cfg.vocab, 16, 4, seed=1),
+                    put_batch=lambda b: {k: jnp.asarray(v)
+                                         for k, v in b.items()},
+                    log_every=1)
+    plain.fit(TrainState(params, opt.init(params)), 3)
+    want = [h["loss"] for h in plain.history]
+    assert len(got) == 3 and np.all(np.isfinite(got))
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-3)
+
+
 def test_loss_decreases_small_lm(tmp_path):
     from repro.launch.steps import make_train_step
     from repro.nn.model import build
