@@ -4,7 +4,9 @@ One observability seam for the whole serving/fleet/lifecycle/train stack:
 
 * :class:`~repro.obs.trace.Tracer` — span/event recorder on a
   deterministic step clock (JSONL export; wall clock opt-in so traces
-  stay bitwise-reproducible);
+  stay bitwise-reproducible); each span is also a profiler annotation,
+  and ``repro.obs.trace`` names the engine's profiler spans and counts
+  compiles;
 * :class:`~repro.obs.trace.EventBus` — the shared event stream (fleet
   router/planner decisions, chip re-programs, scheduler probes) with a
   unified ``step``/``type`` schema and chip/ramp tags;

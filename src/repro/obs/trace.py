@@ -22,13 +22,66 @@ engines, and the recal schedulers all publish on: entries carry the same
 lists (compat accessors on ``FleetEngine.events`` /
 ``RecalScheduler.events`` keep the old views working).  A bus can forward
 onto a tracer so bus events land in the exported JSONL timeline.
+
+Profiler spans.  Every :meth:`Tracer.span` also opens a
+``jax.profiler.TraceAnnotation`` for its duration, recorded or not, so
+the program's spans land on the profiler's clock beside the device ops
+whenever a profiler session runs (and cost about a microsecond each when
+none does).  This module names them all: a JSONL span keeps its short
+name and takes the profiler name ``PROFILE_NAMES`` gives it;
+:func:`annotate` and :func:`annotate_step` open the profiler-only
+sub-spans, which leave no JSONL entry.
+
+Compile counter.  The first :func:`watch_compiles` registers one
+``jax.monitoring`` listener per process, which fires only when JAX
+traces, lowers or compiles a program (or reads one from the persistent
+cache), never in steady state.  ``watch_compiles`` publishes its totals
+as counters in a ``MetricsRegistry``; :func:`compile_totals` reads them
+at a past ``time.perf_counter`` instant.
 """
 
 from __future__ import annotations
 
+import bisect
+import collections
 import json
+import threading
 import time
-from typing import Dict, List, Optional
+import weakref
+from typing import Dict, List, Optional, Tuple
+
+import jax
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+# -- profiler span names ------------------------------------------------
+# PERF.md section 3 names the metric that reads each.
+SERVE_STEP = "serve.step"            # all of ServingEngine.step()
+SERVE_ADMIT = "serve.admit"          # an admission wave
+SERVE_PREFILL = "serve.prefill"      # prefill calls of one wave
+SERVE_SCATTER = "serve.scatter"      # prefilled rows into their slots
+SERVE_DECODE = "serve.decode"        # one batch decode step, inputs to
+#                                      bookkeeping:
+DECODE_INPUTS = "serve.decode.inputs"          # host -> device inputs
+DECODE_DISPATCH = "serve.decode.dispatch"      # the jitted call
+DECODE_SYNC = "serve.decode.sync"              # waiting for the tokens
+DECODE_BOOKKEEP = "serve.decode.bookkeep"      # per-row host work
+SERVE_WARMUP = "serve.warmup"        # ServingEngine.warmup()
+SERVE_COMPILE = "serve.compile"      # lowering and compiling one program
+
+# JSONL span name -> profiler name (others keep their own name)
+PROFILE_NAMES = {"admit": SERVE_ADMIT, "prefill": SERVE_PREFILL,
+                 "decode": SERVE_DECODE}
+
+
+def annotate(name: str) -> TraceAnnotation:
+    """A profiler-only span (no JSONL entry): ``with annotate(NAME):``."""
+    return TraceAnnotation(name)
+
+
+def annotate_step(name: str, step: int) -> StepTraceAnnotation:
+    """A profiler-only span that also marks step ``step`` for the
+    profiler's step view."""
+    return StepTraceAnnotation(name, step_num=step)
 
 
 class Tracer:
@@ -100,17 +153,22 @@ class _Span:
         self._attrs = dict(attrs)
         self._start_step = 0
         self._start_wall = 0.0
+        self._note = None
 
     def set(self, **attrs) -> None:
         self._attrs.update(attrs)
 
     def __enter__(self) -> "_Span":
+        self._note = TraceAnnotation(PROFILE_NAMES.get(self._name,
+                                                       self._name))
+        self._note.__enter__()
         self._start_step = self._t.step
         if self._t.wall_clock:
             self._start_wall = time.time()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        self._note.__exit__(exc_type, exc, tb)
         t = self._t
         if not t.enabled:
             return
@@ -177,3 +235,93 @@ def read_jsonl(path: str) -> List[dict]:
             if line:
                 out.append(json.loads(line))
     return out
+
+
+# -- compile counter ----------------------------------------------------
+
+# jax.monitoring events whose time counts as compiling: tracing to a
+# jaxpr, lowering, and the backend compile, which holds the persistent
+# cache's read on a hit.  One backend event is one executable built.
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_COMPILE_EVENTS = frozenset({"/jax/core/compile/jaxpr_trace_duration",
+                             "/jax/core/compile/jaxpr_to_mlir_module_duration",
+                             _BACKEND_EVENT})
+
+
+class _CompileWatch:
+    """Process-wide totals of programs built and seconds spent building
+    them, with a log of the totals after each event on the
+    ``time.perf_counter`` clock.  Tracing nests (a jitted function traces
+    the jitted functions it calls), so the seconds are the union of the
+    events' intervals, not their sum."""
+
+    def __init__(self):
+        self.lock = threading.Lock()
+        self.programs = 0
+        self.seconds = 0.0
+        self.spans: List[List[float]] = []     # counted intervals, by end
+        self.log = collections.deque([(time.perf_counter(), 0, 0.0)],
+                                     maxlen=4096)
+        self.registries = weakref.WeakSet()
+        jax.monitoring.register_event_time_span_listener(self.on_span)
+
+    def on_span(self, event: str, start: float, end: float, **_) -> None:
+        if event not in _COMPILE_EVENTS:
+            return
+        with self.lock:
+            inner = 0.0
+            while self.spans and self.spans[-1][0] >= start:
+                s, e = self.spans.pop()
+                inner += e - s
+            if self.spans and self.spans[-1][1] > start:
+                start = self.spans[-1][1]
+            added = max(end - start - inner, 0.0)
+            self.spans.append([start, end])
+            del self.spans[:-64]
+            programs = int(event == _BACKEND_EVENT)
+            self.programs += programs
+            self.seconds += added
+            self.log.append((time.perf_counter(), self.programs,
+                             self.seconds))
+            for reg in list(self.registries):
+                reg.counter("compile.programs").inc(programs)
+                reg.counter("compile.seconds").inc(added)
+
+
+_WATCH: Optional[_CompileWatch] = None
+_WATCH_LOCK = threading.Lock()
+
+
+def watch_compiles(registry) -> None:
+    """Publish the compile counter in ``registry`` from now on:
+    ``compile.programs`` (executables built: backend compiles and
+    persistent-cache reads) and ``compile.seconds`` (tracing, lowering,
+    compiling and cache reading).  The counters are process-wide and
+    unlabelled: a registry shared by a fleet's chips counts each compile
+    once."""
+    global _WATCH
+    registry.counter("compile.programs")
+    registry.counter("compile.seconds")
+    with _WATCH_LOCK:
+        if _WATCH is None:
+            _WATCH = _CompileWatch()
+    with _WATCH.lock:
+        _WATCH.registries.add(registry)
+
+
+def compile_totals(at: Optional[float] = None
+                   ) -> Optional[Tuple[int, float]]:
+    """(programs, seconds) built in this process up to the
+    ``time.perf_counter`` instant ``at`` (default now), counted from the
+    first :func:`watch_compiles`; None before that, or when ``at`` lies
+    before the oldest entry the log still holds."""
+    if _WATCH is None:
+        return None
+    with _WATCH.lock:
+        log = list(_WATCH.log)
+    if at is None:
+        return log[-1][1], log[-1][2]
+    i = bisect.bisect_right([t for t, _, _ in log], at)
+    if i == 0:
+        return None
+    return log[i - 1][1], log[i - 1][2]

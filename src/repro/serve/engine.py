@@ -61,6 +61,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import trace as otrace
+
 
 def serving_params(model, key):
     """``model.init(key)`` as the serve path stores it: float32 leaves in
@@ -86,6 +88,10 @@ class Request:
     eos_id: int = -1                    # -1: never
     # filled by the engine
     generated: Optional[List[int]] = None
+    # when the request was due, on the time.perf_counter clock (None:
+    # when it was submitted); serve.ttft_ms counts from it.  Process-
+    # local, so checkpoints leave it out.
+    arrival_s: Optional[float] = None
 
     def to_dict(self) -> dict:
         return {"uid": self.uid, "prompt": np.asarray(self.prompt).tolist(),
@@ -321,7 +327,7 @@ class ServingEngine:
         # obs layer records is keyed on it (never on wall time), which is
         # what makes seeded traces bitwise-reproducible; checkpointed so a
         # restored deployment continues the clock, not restarts it.
-        self.obs = obs if obs is not None else Obs()
+        self.obs = obs if obs is not None else Obs(trace=False)
         self._step_ord = 0
         self._submit_ord: Dict[int, int] = {}       # uid -> submit step
         self._submit_wall: Dict[int, float] = {}
@@ -342,6 +348,7 @@ class ServingEngine:
         self._m_reprograms = o.counter("serve.reprograms")
         self._m_buckets_dropped = o.counter("serve.prefill_buckets_dropped")
         self._m_decode_rebuilds = o.counter("serve.decode_rebuilds")
+        otrace.watch_compiles(o.metrics)
         # Per-chip energy: price the served params under both peripheries
         # (NL-ADC vs digital-LUT baseline); counters accumulate per
         # processed token so run_offline / fleet sweeps report tok/J.
@@ -465,8 +472,10 @@ class ServingEngine:
         tokens = jnp.zeros((P, bucket), jnp.int32)
         vlen = jnp.zeros((P,), jnp.int32)
         key = self._noise_key if self._noisy else None
-        ex = jax.jit(self._prefill_packed).lower(
-            self.params, self._pack_template(), tokens, vlen, key).compile()
+        with otrace.annotate(otrace.SERVE_COMPILE):
+            ex = jax.jit(self._prefill_packed).lower(
+                self.params, self._pack_template(), tokens, vlen,
+                key).compile()
         self._prefill_exec[bucket] = ex
         # fingerprint AFTER compiling: the trace may have realized
         # threshold banks lazily, and those are part of what it serves
@@ -474,20 +483,31 @@ class ServingEngine:
         return ex
 
     def warmup(self) -> dict:
-        """Pre-compile every prefill bucket executable and the decode step
-        before traffic arrives (MLPerf-offline style: compile time is paid
-        here, not inside the measured burst)."""
+        """Pre-compile every prefill bucket executable, the slot scatter
+        and the decode step before traffic arrives (MLPerf-offline style:
+        compile time is paid here, not inside the measured burst)."""
         out = {"prefill_buckets": [], "decode": True}
-        for b in self.prefill_buckets:
-            self._ensure_prefill_exec(b)
-            out["prefill_buckets"].append(b)
-        # one representative-shape decode call triggers (and caches) the
-        # jit compile; the result is discarded and no engine state — in
-        # particular the noise-key schedule — advances
-        tokens = jnp.zeros((self.max_batch, 1), jnp.int32)
-        positions = jnp.zeros((self.max_batch,), jnp.int32)
-        key = self._noise_key if self._noisy else None
-        self._jit_decode(self.params, self.state, tokens, positions, key)
+        with otrace.annotate(otrace.SERVE_WARMUP):
+            for b in self.prefill_buckets:
+                self._ensure_prefill_exec(b)
+                out["prefill_buckets"].append(b)
+            if self.prefill_buckets:
+                # a scatter of no rows: compiles its ops, moves nothing
+                self._scatter_rows(self._pack_template(), [])
+            # one representative-shape decode call triggers (and caches)
+            # the jit compile; the result is discarded and no engine
+            # state — in particular the noise-key schedule — advances.
+            # Waiting for it leaves nothing of the warm-up queued on the
+            # device, holding memory, when traffic starts.
+            tokens = jnp.zeros((self.max_batch, 1), jnp.int32)
+            positions = jnp.zeros((self.max_batch,), jnp.int32)
+            key = None
+            if self._noisy:
+                key = self._noise_key
+                jax.random.split(key)     # the key schedule's own program
+            with otrace.annotate(otrace.SERVE_COMPILE):
+                jax.block_until_ready(self._jit_decode(
+                    self.params, self.state, tokens, positions, key))
         return out
 
     # -- threshold fingerprints (bucket-aware invalidation) ------------
@@ -612,7 +632,8 @@ class ServingEngine:
         req.generated = []
         self.queue.append(req)
         self._submit_ord[req.uid] = self._step_ord
-        self._submit_wall[req.uid] = time.perf_counter()
+        self._submit_wall[req.uid] = time.perf_counter() \
+            if req.arrival_s is None else req.arrival_s
         self._m_submitted.inc()
         self.obs.trace_event("submit", uid=req.uid,
                              prompt_len=int(len(req.prompt)))
@@ -709,7 +730,8 @@ class ServingEngine:
                     mini_state = self._fill(mini_state, req.prompt,
                                             wave_key)
                 self._bookkeep_admit(slot, req)
-                self._merge_slot(mini_state, slot)
+                with otrace.annotate(otrace.SERVE_SCATTER):
+                    self._merge_slot(mini_state, slot)
 
     def _fill(self, state, prompt, wave_key):
         # Jitted scan over the prompt (minus the last token, which decodes
@@ -782,20 +804,18 @@ class ServingEngine:
                     sp.set(buckets=sp_buckets)
             for row, (slot, req) in enumerate(group):
                 self._bookkeep_admit(slot, req)
-            self._scatter_rows(state, [(row, slot) for row, (slot, _)
-                                       in enumerate(group)])
-            # global index = max over active slots, as in _merge_slot
-            self.state["index"] = jnp.maximum(
-                self.state["index"],
-                jnp.asarray(np.int32(max(self.slot_pos[slot]
-                                         for slot, _ in group))))
+            with otrace.annotate(otrace.SERVE_SCATTER):
+                self._scatter_rows(state, [(row, slot) for row, (slot, _)
+                                           in enumerate(group)])
 
     def _scatter_rows(self, mini, assign):
         """Scatter pack rows into their batch slots (generalizing the
         single-slot :meth:`_merge_slot` to a whole admission wave): per
         leaf, gather the assigned rows along the batch axis and commit
         them only at the assigned slots — exact copies, untouched slots
-        keep their in-flight state bit-for-bit."""
+        keep their in-flight state bit-for-bit.  The shared index becomes
+        the max over the assigned slots' positions, as in
+        :meth:`_merge_slot`."""
         perm = np.zeros(self.max_batch, np.int64)
         mask = np.zeros(self.max_batch, bool)
         for row, slot in assign:
@@ -806,7 +826,7 @@ class ServingEngine:
 
         def sel(big, small, ax):
             if ax < 0:
-                return big        # shared leaves (index) set by the caller
+                return big        # shared leaves (index) set below
             rows = jnp.take(small, perm_j, axis=ax)
             shape = [1] * big.ndim
             shape[ax] = self.max_batch
@@ -815,6 +835,9 @@ class ServingEngine:
 
         self.state = jax.tree.map(sel, self.state, mini,
                                   self._batch_axes())
+        top = max((self.slot_pos[slot] for _, slot in assign), default=0)
+        self.state["index"] = jnp.maximum(self.state["index"],
+                                          jnp.asarray(np.int32(top)))
 
     def _merge_slot(self, mini_state, slot):
         """Copy the single-request cache into batch slot ``slot``."""
@@ -844,52 +867,60 @@ class ServingEngine:
         an earlier step (at most one step of lag; {} while the first step
         is still in flight) — :meth:`detok_flush` joins the backlog.
         """
-        self.obs.set_step(self._step_ord)
-        if self._rejit_pending and all(self.slot_free):
-            # the wave drained: apply the deferred chip re-program, then
-            # resume admission on the fresh traces
-            self._rejit_pending = False
-            self._on_chip_reprogram()
-        if self._detok is not None:
-            self._reap_detok_eos()
-        self._admit()
-        active = [s for s in range(self.max_batch) if not self.slot_free[s]]
-        if not active:
+        with otrace.annotate_step(otrace.SERVE_STEP, self._step_ord):
+            self.obs.set_step(self._step_ord)
+            if self._rejit_pending and all(self.slot_free):
+                # the wave drained: apply the deferred chip re-program,
+                # then resume admission on the fresh traces
+                self._rejit_pending = False
+                self._on_chip_reprogram()
+            if self._detok is not None:
+                self._reap_detok_eos()
+            self._admit()
+            active = [s for s in range(self.max_batch)
+                      if not self.slot_free[s]]
+            if not active:
+                self._step_ord += 1
+                return self._drain_detok() if self._detok is not None \
+                    else {}
+            with self.obs.span("decode", active=len(active)):
+                out = self._step_detok(active) if self._detok is not None \
+                    else self._step_sync(active)
+            self.energy.add_processed(len(active))
+            if self.scheduler is not None and self.scheduler.tick():
+                self._handle_reprogram_due(active)
             self._step_ord += 1
-            return self._drain_detok() if self._detok is not None else {}
-        with self.obs.span("decode", active=len(active)):
-            out = self._step_detok(active) if self._detok is not None \
-                else self._step_sync(active)
-        self.energy.add_processed(len(active))
-        if self.scheduler is not None and self.scheduler.tick():
-            self._handle_reprogram_due(active)
-        self._step_ord += 1
-        return out
+            return out
 
     def _step_sync(self, active) -> Dict[int, int]:
         """The synchronous decode step: dispatch, block on the host
         transfer, do the per-request bookkeeping inline."""
-        tokens = jnp.asarray(self.slot_last[:, None], jnp.int32)
-        positions = jnp.asarray(self.slot_pos, jnp.int32)
-        next_tok, self.state = self._jit_decode(
-            self.params, self.state, tokens, positions, self._next_key())
-        next_np = np.asarray(next_tok)
-        out = {}
-        for s in active:
-            req = self.slot_req[s]
-            tok = int(next_np[s])
-            req.generated.append(tok)
-            out[req.uid] = tok
-            self.slot_last[s] = tok
-            self.slot_pos[s] += 1
-            self._note_token(s, req.uid)
-            done = (len(req.generated) >= req.max_new_tokens
-                    or tok == req.eos_id
-                    or self.slot_pos[s] >= self.max_len - 1)
-            if done:
-                self._note_finish(s, req.uid)
-                self.slot_free[s] = True
-                self.slot_req[s] = None
+        with otrace.annotate(otrace.DECODE_INPUTS):
+            tokens = jnp.asarray(self.slot_last[:, None], jnp.int32)
+            positions = jnp.asarray(self.slot_pos, jnp.int32)
+            key = self._next_key()
+        with otrace.annotate(otrace.DECODE_DISPATCH):
+            next_tok, self.state = self._jit_decode(
+                self.params, self.state, tokens, positions, key)
+        with otrace.annotate(otrace.DECODE_SYNC):
+            next_np = np.asarray(next_tok)
+        with otrace.annotate(otrace.DECODE_BOOKKEEP):
+            out = {}
+            for s in active:
+                req = self.slot_req[s]
+                tok = int(next_np[s])
+                req.generated.append(tok)
+                out[req.uid] = tok
+                self.slot_last[s] = tok
+                self.slot_pos[s] += 1
+                self._note_token(s, req.uid)
+                done = (len(req.generated) >= req.max_new_tokens
+                        or tok == req.eos_id
+                        or self.slot_pos[s] >= self.max_len - 1)
+                if done:
+                    self._note_finish(s, req.uid)
+                    self.slot_free[s] = True
+                    self.slot_req[s] = None
         return out
 
     def _step_detok(self, active) -> Dict[int, int]:
@@ -902,28 +933,34 @@ class ServingEngine:
         slot keeps decoding one speculative token (discarded by the
         worker) and is reaped at the top of the next step.
         """
-        tokens = self._slot_last_dev[:, None]
-        positions = jnp.asarray(self.slot_pos, jnp.int32)
-        next_tok, self.state = self._jit_decode(
-            self.params, self.state, tokens, positions, self._next_key())
-        mask = np.zeros(self.max_batch, bool)
-        for s in active:
-            mask[s] = True
-        self._slot_last_dev = jnp.where(jnp.asarray(mask), next_tok,
-                                        self._slot_last_dev)
-        self._detok.put(next_tok, [(s, self.slot_req[s]) for s in active])
-        for s in active:
-            uid = self.slot_req[s].uid
-            self.slot_pos[s] += 1
-            self._note_token(s, uid)
-            done = (self._slot_ntok[s] >= self.slot_req[s].max_new_tokens
-                    or self.slot_pos[s] >= self.max_len - 1)
-            if done:
-                # the worker still holds its reference; streams finish
-                # landing asynchronously
-                self._note_finish(s, uid)
-                self.slot_free[s] = True
-                self.slot_req[s] = None
+        with otrace.annotate(otrace.DECODE_INPUTS):
+            tokens = self._slot_last_dev[:, None]
+            positions = jnp.asarray(self.slot_pos, jnp.int32)
+            key = self._next_key()
+        with otrace.annotate(otrace.DECODE_DISPATCH):
+            next_tok, self.state = self._jit_decode(
+                self.params, self.state, tokens, positions, key)
+        with otrace.annotate(otrace.DECODE_BOOKKEEP):
+            mask = np.zeros(self.max_batch, bool)
+            for s in active:
+                mask[s] = True
+            self._slot_last_dev = jnp.where(jnp.asarray(mask), next_tok,
+                                            self._slot_last_dev)
+            self._detok.put(next_tok,
+                            [(s, self.slot_req[s]) for s in active])
+            for s in active:
+                uid = self.slot_req[s].uid
+                self.slot_pos[s] += 1
+                self._note_token(s, uid)
+                done = (self._slot_ntok[s]
+                        >= self.slot_req[s].max_new_tokens
+                        or self.slot_pos[s] >= self.max_len - 1)
+                if done:
+                    # the worker still holds its reference; streams
+                    # finish landing asynchronously
+                    self._note_finish(s, uid)
+                    self.slot_free[s] = True
+                    self.slot_req[s] = None
         return self._drain_detok()
 
     def _note_token(self, s: int, uid: int) -> None:

@@ -360,6 +360,103 @@ def test_run_offline_reports_latency_and_energy(smoke_model):
 
 
 # ---------------------------------------------------------------------------
+# Profiler spans, the compile counter, TTFT from the arrival time
+# ---------------------------------------------------------------------------
+
+
+def _host_spans(log_dir):
+    """Every ``serve.*`` event on the host planes of the profiler trace
+    under ``log_dir``: [(name, start_ns, end_ns)] by start."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    out = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+           for plane in ProfileData.from_file(path).planes
+           if plane.name.startswith("/host:")
+           for line in plane.lines for e in line.events
+           if e.name.startswith("serve.")]
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+def test_engine_steps_nest_profiler_spans(smoke_model, tmp_path):
+    """Each step() is one serve.step span holding the decode step's
+    inputs, dispatch, host sync and bookkeeping, nested and in order; the
+    default engine records no JSONL but keeps its profiler spans."""
+    cfg, model, params = smoke_model
+    eng = ServingEngine(model, params, max_batch=2, max_len=48)
+    rng = np.random.default_rng(3)
+    for uid in range(2):
+        eng.submit(Request(uid=uid, prompt=rng.integers(0, cfg.vocab, 5)
+                           .astype(np.int32), max_new_tokens=8))
+    eng.step()                      # admits and compiles outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        for _ in range(3):
+            eng.step()
+    assert eng.obs.tracer.entries == []
+    spans = _host_spans(str(tmp_path))
+    steps = [s for s in spans if s[0] == "serve.step"]
+    assert len(steps) == 3
+    parts = ["serve.decode.inputs", "serve.decode.dispatch",
+             "serve.decode.sync", "serve.decode.bookkeep"]
+    for _, t0, t1 in steps:
+        inside = [s for s in spans if t0 <= s[1] and s[2] <= t1
+                  and s[0] != "serve.step"]
+        assert [s[0] for s in inside] == ["serve.decode"] + parts
+        (_, d0, d1), *subs = inside
+        assert all(d0 <= a and b <= d1 for _, a, b in subs)
+        assert all(a[2] <= b[1] for a, b in zip(subs, subs[1:]))
+
+
+def test_compile_counter_counts_only_new_programs(smoke_model):
+    """After warmup() a wave of the warmed shapes builds no program; a
+    new prompt bucket builds at least one."""
+    cfg, model, params = smoke_model
+    eng = ServingEngine(model, params, max_batch=2, max_len=48,
+                        prefill="bucketed", prefill_buckets=(8, 16),
+                        pack_prefill=True)
+    programs = eng.obs.metrics.find("compile.programs")
+    seconds = eng.obs.metrics.find("compile.seconds")
+    eng.warmup()
+    assert programs.value >= 3 and seconds.value > 0
+    before = programs.value, seconds.value
+    rng = np.random.default_rng(4)
+    for uid, n in enumerate((6, 14)):
+        eng.submit(Request(uid=uid, prompt=rng.integers(0, cfg.vocab, n)
+                           .astype(np.int32), max_new_tokens=3))
+    assert eng.run_to_completion() == 6
+    assert (programs.value, seconds.value) == before
+    eng._ensure_prefill_exec(32)
+    assert programs.value >= before[0] + 1 and seconds.value > before[1]
+    from repro.obs.trace import compile_totals
+
+    now = compile_totals()
+    assert now[0] >= programs.value and now[1] >= seconds.value
+
+
+def test_ttft_counts_from_arrival(smoke_model):
+    """serve.ttft_ms counts from Request.arrival_s when it is set, else
+    from submit()."""
+    import time
+
+    cfg, model, params = smoke_model
+    eng = ServingEngine(model, params, max_batch=2, max_len=48)
+    rng = np.random.default_rng(5)
+    due = time.perf_counter() - 1000.0
+    for uid, arrival in enumerate((due, None)):
+        eng.submit(Request(uid=uid, prompt=rng.integers(0, cfg.vocab, 5)
+                           .astype(np.int32), max_new_tokens=2,
+                           arrival_s=arrival))
+    eng.run_to_completion()
+    ttft = eng.obs.metrics.find("serve.ttft_ms")
+    assert ttft.count == 2
+    assert ttft.max >= 1000.0 * 1e3 > ttft.min
+
+
+# ---------------------------------------------------------------------------
 # Replay CLI
 # ---------------------------------------------------------------------------
 
