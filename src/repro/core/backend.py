@@ -15,8 +15,9 @@ the whole config grid runs on either implementation:
   pass per batch row.  The kernels run compiled on a TPU and in interpret
   mode elsewhere (``repro.kernels.interpret_mode``).
   Block sizes resolve per kernel x shape through the
-  :mod:`repro.kernels.tune` cache at trace time, defaulting bitwise to the
-  historical ``DEFAULT_BLOCKS`` on a cache miss.
+  :mod:`repro.kernels.tune` cache at trace time; on a cache miss each
+  kernel's ``DEFAULT_BLOCKS``, except the fused matmul's skinny plan below
+  256 rows (:func:`repro.kernels.fused_matmul_nladc.plan_blocks`).
 
 The Pallas kernels are forward-only; each is wrapped in ``jax.custom_vjp``
 whose backward re-derives the reference path's straight-through gradients

@@ -11,7 +11,8 @@ Kernels run compiled on a TPU and in Pallas interpret mode everywhere
 else (``interpret_mode()`` decides from the platform alone).
 Block sizes resolve through the :mod:`repro.kernels.tune` cache
 (``REPRO_KERNEL_CACHE`` / ``--kernel-blocks``), falling back to each
-kernel's ``DEFAULT_BLOCKS``.
+kernel's ``DEFAULT_BLOCKS`` (the fused matmul: its skinny plan below 256
+rows, ``fused_matmul_nladc.plan_blocks``).
 """
 
 from repro.kernels import ref, tune

@@ -9,9 +9,18 @@ round-trips (vs. matmul -> write 16 GB/s-bound activations -> read -> act).
 Grid (i, j, k) over (M/bm, N/bn, K/bk); the f32 accumulator tile lives in
 VMEM scratch across the k-steps; the NL-ADC epilogue (thermometer compare
 + affine decode + optional bias) fires on the last k-step and writes the
-only output.  Every operand block is 2-D (or an untiled (P,) table) so the
-TPU tiling accepts it: the bias is a (1, bn) row of a (1, N) array, and
-the fast-path bank rows a (1, P) slice of an (n_blocks, 1, P) table.
+only output.  bf16 x and W feed the MXU as stored, one pass, into the f32
+accumulator (the products are exact in f32); f32 or mixed operands are
+dotted in f32, so nothing stored in f32 is rounded to bf16.
+
+Blocks (``plan_blocks``, unless an override or the tune cache names
+them): ``DEFAULT_BLOCKS`` from 256 rows up (prefill, training); below, a
+skinny plan for decode-sized calls, rows fitted to the sublane tile and
+the whole of K in one grid step.
+
+Every operand block is 2-D (or an untiled (P,) table) so the TPU tiling
+accepts it: the bias is a (1, bn) row of a (1, N) array, and the
+fast-path bank rows a (1, P) slice of an (n_blocks, 1, P) table.
 """
 
 from __future__ import annotations
@@ -31,6 +40,35 @@ from repro.kernels.ref import (closed_form_decode, decode_mode, decode_params,
                                thermometer_count)
 
 DEFAULT_BLOCKS = (256, 256, 512)   # (bm, bn, bk)
+# the skinny plan's largest (bk, bn) weight tile: K in one step up to here
+SKINNY_W_TILE_BYTES = 4 * 1024 * 1024
+
+
+def _round_up(a: int, mult: int) -> int:
+    return -(-a // mult) * mult
+
+
+def plan_blocks(m: int, k: int, n: int, x_dtype,
+                w_dtype) -> Tuple[int, int, int]:
+    """The blocks of one (m, k, n) call when neither an override nor the
+    tune cache names them.
+
+    ``DEFAULT_BLOCKS`` from ``DEFAULT_BLOCKS[0]`` rows up.  Fewer rows (a
+    decode step's batch, an expert's capacity) take the skinny plan: bm is
+    the rows rounded up to x's sublane tile (8 for f32, 16 for bf16), so no
+    MXU pass or epilogue runs on padding rows, and bk is the whole of K
+    (rounded up to 128 lanes) while a (bk, bn) weight tile fits
+    ``SKINNY_W_TILE_BYTES``, so each lane block is one grid step.  bn stays
+    ``DEFAULT_BLOCKS[1]``.
+    """
+    bm, bn, bk = DEFAULT_BLOCKS
+    if m >= bm:
+        return DEFAULT_BLOCKS
+    sublanes = 32 // jnp.dtype(x_dtype).itemsize
+    k_all = _round_up(k, 128)
+    if k_all * bn * jnp.dtype(w_dtype).itemsize <= SKINNY_W_TILE_BYTES:
+        bk = k_all
+    return _round_up(max(m, 1), sublanes), bn, bk
 
 
 def _kernel(*refs, n_k: int, y0, lsb_l, lsb_r, m, mode, has_bias,
@@ -45,9 +83,11 @@ def _kernel(*refs, n_k: int, y0, lsb_l, lsb_r, m, mode, has_bias,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(
-        x_ref[...].astype(jnp.float32), w_ref[...].astype(jnp.float32),
-        preferred_element_type=jnp.float32)
+    x, w = x_ref[...], w_ref[...]
+    if not x.dtype == w.dtype == jnp.bfloat16:
+        # f32 or mixed storage: an f32 dot, nothing rounded below its dtype
+        x, w = x.astype(jnp.float32), w.astype(jnp.float32)
+    acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
 
     @pl.when(k == n_k - 1)
     def _epilogue():

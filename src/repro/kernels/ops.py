@@ -110,15 +110,18 @@ def fused_matmul_nladc(x, w, ramp: Ramp, bias=None, *, thresholds=None,
     """NLADC(x @ w + bias) with batch-dims flattened into M.
 
     ``thresholds`` may be a :class:`BankedThresholds` over w's output
-    columns (one ramp per crossbar col-tile).
+    columns (one ramp per crossbar col-tile).  Rows are padded to the
+    resolved bm: below 256 rows that is the skinny plan's, the rows
+    rounded up to x's sublane tile (``fused_matmul_nladc.plan_blocks``).
     """
     lead = x.shape[:-1]
     k = x.shape[-1]
     n = w.shape[-1]
     xf = x.reshape(-1, k)
     m0 = xf.shape[0]
-    blk = blocks or tune.resolve_blocks("fused_matmul_nladc", (m0, k, n),
-                                        x.dtype)
+    blk = blocks or tune.resolve_blocks(
+        "fused_matmul_nladc", (m0, k, n), x.dtype,
+        fallback=_fm.plan_blocks(m0, k, n, x.dtype, w.dtype))
     thr = _resolve_thr(thresholds, n, blk[1])
     xf = _pad_to(_pad_to(xf, blk[0], 0), blk[2], 1)
     wp = _pad_to(_pad_to(w, blk[2], 0), blk[1], 1)

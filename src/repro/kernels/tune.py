@@ -19,8 +19,13 @@ seam that makes the choice data-driven without touching kernel code:
          ``set_block_overrides`` / ``REPRO_KERNEL_BLOCKS`` env;
       2. the active cache — ``set_active_cache`` / ``--kernel-cache`` CLI /
          ``REPRO_KERNEL_CACHE`` env (path to a cache JSON);
-      3. the kernel's ``DEFAULT_BLOCKS`` (bitwise exactly the pre-tune
-         behaviour — a cache miss can never change numerics).
+      3. the caller's shape rule, where it passes one (``fallback``),
+         else the kernel's ``DEFAULT_BLOCKS``.  ``ops.fused_matmul_nladc``
+         passes ``fused_matmul_nladc.plan_blocks``: ``DEFAULT_BLOCKS`` at
+         256 rows or more, a skinny plan below (rows fitted to the sublane
+         tile, the whole of K in one step).  A miss is bitwise the
+         pre-autotune behaviour everywhere except there: a skinny call's
+         new bk groups the f32 sums differently.
 
 * ``autotune`` — the sweep harness.  Where Pallas compiles (on a TPU)
   each candidate is timed and the fastest wins; in interpret mode (CI)
@@ -278,13 +283,15 @@ def configure(blocks_spec: str = "", cache_path: str = "") -> None:
         set_active_cache(TuneCache.load(cache_path))
 
 
-def resolve_blocks(kernel: str, shape: Sequence[int],
-                   dtype=jnp.float32) -> Tuple[int, ...]:
+def resolve_blocks(kernel: str, shape: Sequence[int], dtype=jnp.float32,
+                   fallback: Optional[Sequence[int]] = None
+                   ) -> Tuple[int, ...]:
     """The trace-time block choice for one kernel call.
 
-    Explicit override > active-cache hit > ``DEFAULT_BLOCKS``.  The
-    fallback is the kernel module's historical constant, so a cache miss
-    is bitwise the pre-autotune behaviour.
+    Explicit override > active-cache hit > ``fallback`` > the kernel
+    module's historical ``DEFAULT_BLOCKS``.  Without a ``fallback`` a cache
+    miss is bitwise the pre-autotune behaviour; the fused matmul's
+    fallback differs from it below 256 rows only (module docstring).
     """
     ov = _OVERRIDES.get(kernel) or _env_overrides().get(kernel)
     if ov is not None:
@@ -294,6 +301,8 @@ def resolve_blocks(kernel: str, shape: Sequence[int],
         hit = cache.lookup(kernel, shape, dtype)
         if hit is not None:
             return hit
+    if fallback is not None:
+        return tuple(fallback)
     return default_blocks(kernel)
 
 

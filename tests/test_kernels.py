@@ -1,8 +1,12 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype/activation sweeps."""
 
+import importlib
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import core
 
 from repro.core.nladc import build_ramp, nladc_reference
 from repro.kernels import ops, ref
@@ -51,6 +55,78 @@ def test_fused_matmul_sweep(mkn, dtype, rng):
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                rtol=1e-2, atol=2e-2)
+
+
+def _pallas_eqn(fn, *args):
+    """The one ``pallas_call`` in ``fn``'s jaxpr."""
+    eqns = [e for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
+            if e.primitive.name == "pallas_call"]
+    assert len(eqns) == 1, eqns
+    return eqns[0]
+
+
+def _kernel_dot_dtypes(eqn):
+    """Operand dtypes of every dot in the kernel body."""
+    out, todo = [], [eqn.params["jaxpr"]]
+    while todo:
+        jaxpr = todo.pop()
+        for e in jaxpr.eqns:
+            if e.primitive.name == "dot_general":
+                out.append(tuple(v.aval.dtype for v in e.invars))
+            todo.extend(core.jaxprs_in_params(e.params))
+    return out
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("m", [1, 16, 32, 37])
+def test_fused_matmul_decode_shapes(m, dtype, rng):
+    """Decode-sized calls take the skinny plan: rows padded to x's sublane
+    tile (not to 256), all of K in one step, bf16 operands dotted as
+    stored; the result matches the oracle at the sweep's tolerance."""
+    k, n = 2048, 640
+    ramp = build_ramp("swish", 5)
+    x = jnp.asarray(rng.normal(0, 0.4, (m, k)).astype(np.float32), dtype)
+    w = jnp.asarray(rng.normal(0, 0.2, (k, n)).astype(np.float32), dtype)
+    got = ops.fused_matmul_nladc(x, w, ramp)
+    want = ref.fused_matmul_nladc(x, w, ramp)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=1e-2, atol=2e-2)
+    sublanes = 16 if dtype == jnp.bfloat16 else 8
+    eqn = _pallas_eqn(lambda a, b: ops.fused_matmul_nladc(a, b, ramp), x, w)
+    assert eqn.invars[0].aval.shape == (-(-m // sublanes) * sublanes, k)
+    assert eqn.params["grid_mapping"].grid == (1, 3, 1)   # n 640, bn 256
+    assert _kernel_dot_dtypes(eqn) == [(jnp.dtype(dtype),) * 2]
+
+
+def test_fused_matmul_plan_blocks():
+    """From 256 rows the historical DEFAULT_BLOCKS tile the call; below,
+    the skinny plan (the decode call: 32 rows, all of K in one step)."""
+    fm = importlib.import_module("repro.kernels.fused_matmul_nladc")
+    assert fm.plan_blocks(256, 2048, 11008, jnp.bfloat16, jnp.bfloat16) \
+        == fm.DEFAULT_BLOCKS
+    assert fm.plan_blocks(4096, 2048, 11008, jnp.float32, jnp.float32) \
+        == fm.DEFAULT_BLOCKS
+    assert fm.plan_blocks(32, 2048, 11008, jnp.bfloat16, jnp.bfloat16) \
+        == (32, 256, 2048)
+    # a (K, bn) tile past the budget keeps the default bk
+    assert fm.plan_blocks(32, 16384, 256, jnp.float32, jnp.float32)[2] \
+        == fm.DEFAULT_BLOCKS[2]
+
+
+def test_fused_matmul_mixed_dtypes_keep_f32_dot(rng):
+    """A bf16 x against an f32 W is dotted in f32: W is never rounded to
+    bf16, so the result is the f32 oracle's."""
+    ramp = build_ramp("swish", 5)
+    x = jnp.asarray(rng.normal(0, 0.4, (32, 512)).astype(np.float32),
+                    jnp.bfloat16)
+    w = jnp.asarray(rng.normal(0, 0.2, (512, 256)).astype(np.float32))
+    eqn = _pallas_eqn(lambda a, b: ops.fused_matmul_nladc(a, b, ramp), x, w)
+    assert _kernel_dot_dtypes(eqn) == [(jnp.dtype(jnp.float32),) * 2]
+    np.testing.assert_allclose(
+        np.asarray(ops.fused_matmul_nladc(x, w, ramp), np.float32),
+        np.asarray(ref.fused_matmul_nladc(x, w, ramp), np.float32),
+        rtol=1e-2, atol=2e-2)
 
 
 def test_fused_matmul_batch_dims(rng):

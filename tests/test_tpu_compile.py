@@ -25,7 +25,10 @@ from repro.kernels import ops
 
 D_MODEL, D_FF = 2048, 11008
 N_HEADS, N_KV, HEAD_DIM = 16, 2, 128
-DECODE_ROWS = 4                 # the serving engine's max_batch
+DECODE_ROWS = 4                 # a small decode batch
+# fused matmul rows: small and the benchmark engine's max_batch (32) take
+# the skinny block plan, 4096 (prefill, training) the 256-row default
+MATMUL_ROWS = [4, 32, 4096]
 BITS = 5
 
 
@@ -80,25 +83,28 @@ def _p():
     return int(np.asarray(_ramp().thresholds).shape[0])
 
 
+@pytest.mark.parametrize("rows", MATMUL_ROWS)
 @pytest.mark.parametrize("w_dtype", [jnp.float32, jnp.bfloat16])
-def test_fused_matmul_nladc_shared_ramp(compiled, w_dtype):
+def test_fused_matmul_nladc_shared_ramp(compiled, w_dtype, rows):
     """The MLP gate with the NL-ADC epilogue, one shared ramp."""
     ramp = _ramp()
     compiled(lambda x, w: ops.fused_matmul_nladc(x, w, ramp),
-             ((DECODE_ROWS, D_MODEL), jnp.bfloat16),
+             ((rows, D_MODEL), jnp.bfloat16),
              ((D_MODEL, D_FF), w_dtype))
 
 
-def test_fused_matmul_nladc_bias(compiled):
+@pytest.mark.parametrize("rows", MATMUL_ROWS)
+def test_fused_matmul_nladc_bias(compiled, rows):
     ramp = _ramp()
     compiled(lambda x, w, b: ops.fused_matmul_nladc(x, w, ramp, bias=b),
-             ((DECODE_ROWS, D_MODEL), jnp.bfloat16),
+             ((rows, D_MODEL), jnp.bfloat16),
              ((D_MODEL, D_FF), jnp.float32), ((D_FF,), jnp.float32))
 
 
+@pytest.mark.parametrize("rows", MATMUL_ROWS)
 @pytest.mark.parametrize("bank_cols", [512, 96],
                          ids=["fast_path", "dense_banked"])
-def test_fused_matmul_nladc_banked(compiled, bank_cols):
+def test_fused_matmul_nladc_banked(compiled, bank_cols, rows):
     """Threshold banks: 512-column col-tiles take the (P,) bank-row fast
     path; 96 does not divide the lane block and keeps the (bn, P) layout."""
     ramp = _ramp()
@@ -108,7 +114,7 @@ def test_fused_matmul_nladc_banked(compiled, bank_cols):
         return ops.fused_matmul_nladc(x, w, ramp,
                                       thresholds=BankedThresholds(thr, bm))
 
-    compiled(fn, ((DECODE_ROWS, D_MODEL), jnp.bfloat16),
+    compiled(fn, ((rows, D_MODEL), jnp.bfloat16),
              ((D_MODEL, D_FF), jnp.float32), ((bm.n_banks, _p()), jnp.float32))
 
 
