@@ -66,8 +66,26 @@ class ModelConfig:
     top_k: int = 0
     capacity_factor: float = 1.0
     router_aux_coef: float = 0.001
-    router_score: str = "softmax"   # softmax | sigmoid (moonlight-style)
+    # softmax | sigmoid: DeepSeek-V3's noaux_tc router (a per-expert
+    # correction bias added for the choice only, weights normalised)
+    router_score: str = "softmax"
+    routed_scaling_factor: float = 1.0     # scales the routed experts' sum
     moe_impl: str = "gspmd"         # gspmd | ep_shardmap (§Perf iteration)
+    # The chip's share of an expert-parallel deployment: the router spans
+    # all ``n_experts``; this layer holds ``experts_held`` of them (0 =
+    # all), experts ``expert_offset`` onward, and computes their part only.
+    experts_held: int = 0
+    expert_offset: int = 0
+    # Leading dense layers in front of the MoE stack, and their MLP width.
+    n_dense_layers: int = 0
+    dense_d_ff: int = 0
+    # --- latent attention (MLA; DeepSeek-V2/V3) ---
+    # kv_lora_rank > 0 selects MLA: keys and values come from a cached
+    # latent of this width plus a shared RoPE key of qk_rope_head_dim.
+    # head_dim is both qk_nope_head_dim and v_head_dim (equal in every
+    # published MLA model).
+    kv_lora_rank: int = 0
+    qk_rope_head_dim: int = 0
     # --- hybrid (recurrentgemma) ---
     block_pattern: Tuple[str, ...] = ()   # e.g. ("rec", "rec", "attn")
     window: int = 0                        # local-attention window (0 = global)
@@ -132,6 +150,11 @@ class ModelConfig:
         return self.n_kv_heads * self.head_dim
 
     @property
+    def n_held(self) -> int:
+        """Routed experts held by this chip's MoE layers."""
+        return self.experts_held or self.n_experts
+
+    @property
     def is_attention_free(self) -> bool:
         return self.family == "ssm"
 
@@ -159,11 +182,10 @@ class ModelConfig:
             att = 2 * d * (self.q_dim + self.kv_dim + self.q_dim)  # self+x-attn q,o
             blk = att + 2 * d * ff  # gelu mlp (2 mats)
             return emb + (self.n_enc_layers + self.n_dec_layers) * blk
-        att = d * (self.q_dim + 2 * self.kv_dim) + self.q_dim * d
+        att = self._attn_params()
         mlp = 3 * d * ff
         if self.family == "moe":
-            mlp = (self.n_experts + self.n_shared_experts) * 3 * d * ff \
-                + d * self.n_experts
+            return self._moe_params(emb, att, self.n_held)
         if self.family == "ssm":
             # in_proj packs [z, x] (2*din) plus B/C/dt rows (d_state- and
             # head-sized, negligible at these widths); out_proj din*d;
@@ -183,12 +205,37 @@ class ModelConfig:
         """Active (per-token) params — differs from n_params for MoE."""
         if self.family != "moe":
             return self.n_params()
-        d, ff, v = self.d_model, self.d_ff, self.padded_vocab
-        att = d * (self.q_dim + 2 * self.kv_dim) + self.q_dim * d
-        mlp_active = (self.top_k + self.n_shared_experts) * 3 * d * ff \
-            + d * self.n_experts
-        emb = v * d * (1 if self.tie_embeddings else 2)
-        return emb + self.n_layers * (att + mlp_active)
+        emb = self.padded_vocab * self.d_model \
+            * (1 if self.tie_embeddings else 2)
+        return self._moe_params(emb, self._attn_params(), self.top_k)
+
+    def _attn_params(self) -> int:
+        d = self.d_model
+        if self.kv_lora_rank:
+            # MLA: q_proj, kv_a_proj_with_mqa, kv_a_layernorm, kv_b_proj
+            # (a key and a value per head), o_proj
+            h, r = self.n_heads, self.kv_lora_rank
+            hd, rd = self.head_dim, self.qk_rope_head_dim
+            return (d * h * (hd + rd) + d * (r + rd) + r + r * h * 2 * hd
+                    + h * hd * d)
+        return d * (self.q_dim + 2 * self.kv_dim) + self.q_dim * d
+
+    def _moe_params(self, emb: int, att: int, n_experts: int) -> int:
+        """Embedding, attention, ``n_experts`` routed experts and the shared
+        ones in each MoE layer, the router (and its correction bias), and
+        the leading dense layers.  MLA models are counted exactly, norm
+        scales included, against their published totals."""
+        d, ff = self.d_model, self.d_ff
+        router = d * self.n_experts
+        if self.router_score == "sigmoid":
+            router += self.n_experts
+        moe = (n_experts + self.n_shared_experts) * 3 * d * ff + router
+        n_moe = self.n_layers - self.n_dense_layers
+        total = emb + self.n_layers * att + n_moe * moe \
+            + self.n_dense_layers * 3 * d * self.dense_d_ff
+        if self.kv_lora_rank:
+            total += (2 * self.n_layers + 1) * d
+        return total
 
     def _pattern(self) -> Tuple[str, ...]:
         """Full per-layer block-type sequence."""
@@ -230,7 +277,7 @@ ARCH_NAMES = (
     "granite-34b",
     "granite-3-8b",
     "qwen2.5-3b",
-    "moonshot-v1-16b-a3b",
+    "moonlight-16b-a3b",
     "deepseek-moe-16b",
     "recurrentgemma-9b",
     "mamba2-370m",

@@ -12,7 +12,7 @@ the whole config grid runs on either implementation:
   one elementwise pass, decode attention dequantizes int8 KV per-tile, the
   MoE gate einsum is the fused matmul vmapped over experts, and the
   non-int8 cached-attention path (bucketed prefill + decode) is one Pallas
-  pass per batch row.  The kernels run compiled on a TPU and in interpret
+  pass per batch row, as is latent (MLA) attention over its cache.  The kernels run compiled on a TPU and in interpret
   mode elsewhere (``repro.kernels.interpret_mode``).
   Block sizes resolve per kernel x shape through the
   :mod:`repro.kernels.tune` cache at trace time; on a cache miss each
@@ -156,6 +156,17 @@ class RefBackend:
         from repro.nn.attention import attend_full
 
         return attend_full(q, k, v, mask)
+
+    def mla_decode_attention(self, q, ckv, mask, *, rank: int,
+                             scale: float):
+        """One-query attention over a latent (MLA) decode cache.
+
+        q: (B, H, W) absorbed queries [q_c, q_r]; ckv: (B, W, S) cached
+        [c, k_r]; mask: (B, S) bool -> (B, H, rank).  The ref path IS
+        ``nn.attention.mla_attend``."""
+        from repro.nn.attention import mla_attend
+
+        return mla_attend(q, ckv, mask, rank=rank, scale=scale)
 
 
 # ---------------------------------------------------------------------------
@@ -372,6 +383,33 @@ def _pallas_prefill_attention_fn():
     return _cached("prefill_attention", (), build)
 
 
+def _pallas_mla_decode_fn(rank: int, scale: float):
+    def build():
+        def raw(q, ckv, mask):
+            from repro.kernels import ops
+
+            return ops.mla_decode_attention(q, ckv, mask, rank=rank,
+                                            scale=scale)
+
+        def fwd(q, ckv, mask):
+            return raw(q, ckv, mask), (q, ckv, mask)
+
+        def bwd(res, ct):
+            from repro.nn.attention import mla_attend
+
+            q, ckv, mask = res
+            _, vjp = jax.vjp(lambda q_, c_: mla_attend(
+                q_, c_, mask, rank=rank, scale=scale), q, ckv)
+            dq, dc = vjp(ct)
+            return (dq, dc, None)
+
+        fn = jax.custom_vjp(raw)
+        fn.defvjp(fwd, bwd)
+        return fn
+
+    return _cached("mla_decode_attention", (rank, scale), build)
+
+
 class PallasBackend(RefBackend):
     """Fused Pallas kernels; falls back to ref only where no kernel exists
     (the raw activation-less crossbar MAC — by design the upstream GEMM
@@ -427,6 +465,10 @@ class PallasBackend(RefBackend):
 
     def prefill_attention(self, q, k, v, mask):
         return _pallas_prefill_attention_fn()(q, k, v, mask)
+
+    def mla_decode_attention(self, q, ckv, mask, *, rank: int,
+                             scale: float):
+        return _pallas_mla_decode_fn(rank, scale)(q, ckv, mask)
 
 
 # ---------------------------------------------------------------------------

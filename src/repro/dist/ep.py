@@ -45,11 +45,13 @@ def moe_apply_ep(p, x, *, top_k: int, capacity_factor: float,
                  act: AnalogActivation, router_score: str = "softmax",
                  router_act: Optional[AnalogActivation] = None,
                  key=None, ep_axis: str = "model",
-                 return_aux: bool = False):
+                 return_aux: bool = False, expert_offset: int = 0,
+                 routed_scaling: float = 1.0):
     """Drop-in for :func:`repro.nn.moe.moe_apply` with explicit all-to-all.
 
-    x: (B, S, d).  Requires E % model, S % model, B % data to all be 0 for
-    the shard_map path; otherwise delegates to the GSPMD implementation.
+    x: (B, S, d).  Requires every expert held here, E % model, S % model,
+    B % data to all be 0 for the shard_map path; otherwise delegates to
+    the GSPMD implementation.
     """
     info = _mesh_info(ep_axis)
     n_experts = p["router"].shape[-1]
@@ -61,6 +63,7 @@ def moe_apply_ep(p, x, *, top_k: int, capacity_factor: float,
         for ax in baxes:
             d_size *= sizes[ax]
         usable = (m_size > 1
+                  and p["w_gate"].shape[0] == n_experts
                   and n_experts % m_size == 0
                   and x.shape[1] % m_size == 0
                   and x.shape[0] % d_size == 0)
@@ -68,7 +71,8 @@ def moe_apply_ep(p, x, *, top_k: int, capacity_factor: float,
         return MOE.moe_apply(
             p, x, top_k=top_k, capacity_factor=capacity_factor, act=act,
             router_score=router_score, router_act=router_act, key=key,
-            return_aux=return_aux)
+            return_aux=return_aux, expert_offset=expert_offset,
+            routed_scaling=routed_scaling)
 
     tok_axes = baxes + (ep_axis,)          # axes that partition the tokens
 
@@ -84,9 +88,9 @@ def moe_apply_ep(p, x, *, top_k: int, capacity_factor: float,
             key_l = jax.random.fold_in(key_l,
                                        COLL.replica_index(tok_axes))
 
-        logits = xf @ pl["router"].astype(xf.dtype)
-        gates, idx, probs_f32 = MOE.router_gates(
-            logits, top_k, router_score, router_act)
+        gates, idx, probs_f32 = MOE.route(
+            pl, xf, top_k=top_k, router_score=router_score,
+            router_act=router_act, scaling=routed_scaling)
 
         capacity = MOE.expert_capacity(n, top_k, n_experts, capacity_factor)
         st, sg, dest, valid = MOE.dispatch_plan(idx, gates, n, n_experts,

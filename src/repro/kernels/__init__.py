@@ -18,8 +18,8 @@ rows, ``fused_matmul_nladc.plan_blocks``).
 from repro.kernels import ref, tune
 from repro.kernels.ops import (analog_tile, flash_decode_int8,
                                fused_matmul_nladc, interpret_mode,
-                               lstm_gates, moe_fused_matmul, nladc,
-                               prefill_attention)
+                               lstm_gates, mla_decode_attention,
+                               moe_fused_matmul, nladc, prefill_attention)
 
 __all__ = [
     "analog_tile",
@@ -27,6 +27,7 @@ __all__ = [
     "fused_matmul_nladc",
     "interpret_mode",
     "lstm_gates",
+    "mla_decode_attention",
     "moe_fused_matmul",
     "nladc",
     "prefill_attention",

@@ -20,6 +20,7 @@ from repro.kernels import crossbar_mac as _cb
 from repro.kernels import flash_decode as _fd
 from repro.kernels import fused_matmul_nladc as _fm
 from repro.kernels import lstm_cell as _lc
+from repro.kernels import mla_decode_attention as _mla
 from repro.kernels import nladc_kernel as _nk
 from repro.kernels import prefill_attention as _pa
 from repro.kernels import tune
@@ -216,6 +217,15 @@ def prefill_attention(q, k, v, mask):
     out = _pa.prefill_attention_pallas(q[:, 0], k, v, mask2,
                                        interpret=interpret_mode())
     return out[:, None]
+
+
+def mla_decode_attention(q, ckv, mask, *, rank: int, scale: float):
+    """One-query attention over a latent (MLA) cache.  q: (B, H, W),
+    ckv: (B, W, S), mask: (B, S) bool -> (B, H, rank), matching
+    ``nn.attention.mla_attend``."""
+    return _mla.mla_decode_attention_pallas(q, ckv, mask, rank=rank,
+                                            scale=scale,
+                                            interpret=interpret_mode())
 
 
 def flash_decode_int8(q, k8, k_scale, v8, v_scale, length, *, block_s=None):

@@ -290,6 +290,147 @@ def decode_self_attention(p, x, cache, index, *, n_heads, n_kv_heads,
 
 
 # ---------------------------------------------------------------------------
+# Latent attention (MLA: DeepSeek-V2/V3, Moonlight)
+# ---------------------------------------------------------------------------
+#
+# Per token, kv_a_proj_with_mqa gives a latent c (rank wide, RMS-normed) and
+# one RoPE key k_r (rope_dim wide) shared by every head; kv_b_proj expands c
+# into each head's key part W_UK c and value W_UV c.  q_proj gives each head
+# a query [q_nope (head_dim), q_r (rope_dim)].  The decode cache holds only
+# [c, k_r] per position: one (rank + rope_dim)-wide leaf, shared by all
+# heads, stored position-minor, (B, rank + rope_dim, S): at published widths
+# 576 lanes would pad to 640, and the chip's default layout then puts the
+# 768 positions minor anyway, so each step would relayout the whole cache
+# for the kernel.  Decode absorbs W_UK into the query (q_c = q_nope W_UK^T,
+# scores q_c c^T + q_r k_r^T) and W_UV into the output (o = (p c) W_UV), so
+# it attends over the latent cache as multi-query attention.  The
+# full-sequence path computes keys and values as published.
+
+def mla_init(key, d_model: int, n_heads: int, head_dim: int, rope_dim: int,
+             rank: int, *, dtype=jnp.float32):
+    kq, ka, kb, ko = jax.random.split(key, 4)
+    return {
+        "q_proj": L.dense_init(kq, d_model, n_heads * (head_dim + rope_dim),
+                               dtype=dtype),
+        "kv_a_proj_with_mqa": L.dense_init(ka, d_model, rank + rope_dim,
+                                           dtype=dtype),
+        "kv_a_layernorm": L.rmsnorm_init(rank),
+        "kv_b_proj": L.dense_init(kb, rank, n_heads * 2 * head_dim,
+                                  dtype=dtype),
+        "o_proj": L.dense_init(ko, n_heads * head_dim, d_model, dtype=dtype),
+    }
+
+
+def _mla_query(p, x, positions, n_heads, head_dim, rope_theta):
+    """-> q_nope (B, S, H, head_dim), q_r (B, S, H, rope_dim) roped."""
+    q = L.dense_apply(p["q_proj"], x)
+    q = _split_heads(q, n_heads, q.shape[-1] // n_heads)
+    return q[..., :head_dim], L.apply_rope(q[..., head_dim:], positions,
+                                           rope_theta)
+
+
+def _mla_latent(p, x, positions, rank, rope_theta, eps):
+    """-> [c, k_r] (B, S, rank + rope_dim): the latent, normed, beside the
+    roped shared key — what the decode cache holds per position."""
+    kv = L.dense_apply(p["kv_a_proj_with_mqa"], x)
+    c = L.rmsnorm_apply(p["kv_a_layernorm"], kv[..., :rank], eps)
+    k_r = L.apply_rope(kv[..., None, rank:], positions, rope_theta)
+    return jnp.concatenate([c, k_r[..., 0, :]], axis=-1)
+
+
+def mla_scale(head_dim: int, rope_dim: int) -> float:
+    return 1.0 / math.sqrt(head_dim + rope_dim)
+
+
+def mla_attention(p, x, *, n_heads, head_dim, rank, rope_theta, eps,
+                  positions=None, kv_chunk: int = 1024, unroll: bool = False):
+    """Causal MLA over a full sequence, keys and values expanded per head."""
+    b, s, _ = x.shape
+    if positions is None:
+        positions = jnp.arange(s)[None, :]
+    q_nope, q_r = _mla_query(p, x, positions, n_heads, head_dim, rope_theta)
+    ckv = _mla_latent(p, x, positions, rank, rope_theta, eps)
+    kv = _split_heads(L.dense_apply(p["kv_b_proj"], ckv[..., :rank]),
+                      n_heads, 2 * head_dim)
+    rope_dim = q_r.shape[-1]
+    k_r = jnp.broadcast_to(ckv[:, :, None, rank:], (b, s, n_heads, rope_dim))
+    q = jnp.concatenate([q_nope, q_r], axis=-1)
+    k = jnp.concatenate([kv[..., :head_dim], k_r], axis=-1)
+    # values are head_dim wide, queries and keys head_dim + rope_dim: pad
+    # the values with zeros to one width and drop those lanes after
+    v = jnp.pad(kv[..., head_dim:], ((0, 0),) * 3 + ((0, rope_dim),))
+
+    def mask_fn(kv_start, kv_len):
+        return kv_start + jnp.arange(kv_len)[None, :] \
+            <= jnp.arange(s)[:, None]
+
+    out = attend_chunked(q, k, v, mask_fn=mask_fn, kv_chunk=kv_chunk,
+                         scale=mla_scale(head_dim, rope_dim), unroll=unroll)
+    return L.dense_apply(p["o_proj"],
+                         out[..., :head_dim].reshape(b, s, -1))
+
+
+def init_mla_cache(batch: int, max_len: int, width: int,
+                   dtype=jnp.bfloat16):
+    """Latent decode cache of one layer: [c, k_r] per position,
+    (B, width, S)."""
+    return {"ckv": jnp.zeros((batch, width, max_len), dtype)}
+
+
+def mla_attend(q, ckv, mask, *, rank: int, scale: float):
+    """One-query attention over a latent cache (the absorbed form).
+
+    q: (B, H, rank + rope_dim) = [q_c, q_r]; ckv: (B, rank + rope_dim, S);
+    mask: (B, S) bool.  Scores over all rank + rope_dim features, values
+    the first ``rank`` -> (B, H, rank).  The ref backend's
+    ``mla_decode_attention``; the Pallas kernel computes the same
+    sequence (scale cast, f32 logits, -1e30 fill, softmax, probabilities
+    cast back)."""
+    qs = q * jnp.asarray(scale, q.dtype)
+    s = jnp.einsum("bhk,bks->bhs", qs, ckv,
+                   preferred_element_type=jnp.float32)
+    s = jnp.where(mask[:, None, :], s, NEG_INF)
+    pr = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bhs,brs->bhr", pr.astype(ckv.dtype), ckv[:, :rank],
+                   preferred_element_type=jnp.float32)
+    return o.astype(q.dtype)
+
+
+def mla_decode(p, x, cache, index, *, n_heads, head_dim, rank, rope_theta,
+               eps, analog_backend: str = "", commit=None):
+    """One-token MLA decode step at position ``index`` -> (y, new_cache).
+
+    ``commit`` (B,) bool, from a masked prefill: rows that do not commit
+    write zeros at ``index``, which is what the fresh state it fills holds
+    there, so no step selects rows over the whole cache.  (Reading each
+    row's old entry back instead would make the compiler relayout the
+    whole position-minor cache at every step.)"""
+    from repro.core import backend as BK
+
+    b = x.shape[0]
+    pos = jnp.full((1, 1), index, dtype=jnp.int32)
+    q_nope, q_r = _mla_query(p, x, pos, n_heads, head_dim, rope_theta)
+    new = _mla_latent(p, x, pos, rank, rope_theta, eps)
+    entry = jnp.swapaxes(new, 1, 2).astype(cache["ckv"].dtype)
+    if commit is not None:
+        entry = jnp.where(commit[:, None, None], entry, 0)
+    ckv = jax.lax.dynamic_update_slice_in_dim(cache["ckv"], entry, index,
+                                              axis=2)
+    w = p["kv_b_proj"]["w"].reshape(rank, n_heads, 2 * head_dim) \
+        .astype(x.dtype)
+    q_c = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], w[..., :head_dim])
+    q = jnp.concatenate([q_c, q_r[:, 0]], axis=-1).astype(ckv.dtype)
+    mask = jnp.broadcast_to(jnp.arange(ckv.shape[2]) <= index,
+                            (b, ckv.shape[2]))
+    o_c = BK.get_backend(analog_backend).mla_decode_attention(
+        q, ckv, mask, rank=rank,
+        scale=mla_scale(head_dim, q_r.shape[-1]))
+    o = jnp.einsum("bhr,rhd->bhd", o_c.astype(x.dtype), w[..., head_dim:])
+    y = L.dense_apply(p["o_proj"], o.reshape(b, 1, n_heads * head_dim))
+    return y, {"ckv": ckv}
+
+
+# ---------------------------------------------------------------------------
 # Cross-attention (whisper decoder)
 # ---------------------------------------------------------------------------
 

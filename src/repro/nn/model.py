@@ -80,6 +80,11 @@ def prefill_cache(model, params, state, tokens, valid_len, *, key=None,
     path.  Chunking falls out of the shared ``index``: call again with the
     next ``B`` columns and the scan resumes at the global position.
 
+    A model that ``commits_rows`` (latent attention) takes the mask into
+    ``decode_step`` (``commit``) instead, so that no step copies the whole
+    cache to select rows: masked rows write zeros at their position, which
+    is what the fresh state the engine prefills holds there.
+
     ``key``: one noise key for the whole wave (all rows of one prefill
     call read the same physical chip instance — noise draws are weight-
     and threshold-shaped, never batch-shaped); per-step keys are derived
@@ -92,13 +97,18 @@ def prefill_cache(model, params, state, tokens, valid_len, *, key=None,
     valid_len = jnp.asarray(valid_len, jnp.int32)
     n_rows = tokens.shape[0]
     i0 = state["index"]
+    commits = getattr(model, "commits_rows", False)
 
     def body(st, inp):
         t, tok = inp                          # t scalar, tok (P,)
         pos = i0 + t
         k = None if key is None else jax.random.fold_in(key, pos)
-        _, new = model.decode_step(params, st, tok[:, None], key=k)
         take = pos < valid_len                # (P,) per-row commit mask
+        if commits:
+            _, new = model.decode_step(params, st, tok[:, None], key=k,
+                                       commit=take)
+            return new, None
+        _, new = model.decode_step(params, st, tok[:, None], key=k)
 
         def sel(old, fresh, ax):
             if ax < 0:

@@ -7,7 +7,11 @@ enc-dec whisper model lives in :mod:`repro.nn.encdec`):
   independent of depth (granite-34b is 88 layers);
 * per-family block bodies: ``attn+mlp``, ``attn+moe``, ``rec+mlp`` (RG-LRU),
   ``ssd``;  the hybrid 1-attn:2-recurrent pattern scans over (rec,rec,attn)
-  groups with the remainder layers unscanned;
+  groups with the remainder layers unscanned; a MoE model's leading dense
+  layers (``n_dense_layers``, MLP width ``dense_d_ff``) run unscanned
+  before its scanned MoE stack;
+* attention is GQA, or latent attention (MLA) where ``kv_lora_rank`` is
+  set, with a latent decode cache;
 * a single NL-ADC activation object (host-precomputed ramp) is shared by all
   layers — it is a closure constant, not a traced param;
 * decode carries a stacked per-layer cache pytree through the same scan.
@@ -33,6 +37,7 @@ from repro.nn import moe as MOE
 from repro.nn import rglru as RG
 from repro.nn import ssd as SSD
 from repro.nn.mlp import make_activation, mlp_apply, mlp_init, mlp_type_for
+from repro.obs import trace as otrace
 
 
 class LM:
@@ -129,17 +134,22 @@ class LM:
                 "norm2": L.rmsnorm_init(d),
                 "mlp": mlp_init(ks[1], d, cfg.d_ff, self.mlp_kind),
             }
-        blk = {
-            "norm1": L.rmsnorm_init(d),
-            "attn": A.attn_init(ks[0], d, cfg.n_heads, cfg.n_kv_heads,
-                                cfg.head_dim, qkv_bias=cfg.qkv_bias),
-            "norm2": L.rmsnorm_init(d),
-        }
-        if kind == "moe_attn":
-            blk["moe"] = MOE.moe_init(ks[1], d, cfg.d_ff, cfg.n_experts,
-                                      cfg.n_shared_experts, self.mlp_kind)
+        if cfg.kv_lora_rank:
+            attn = A.mla_init(ks[0], d, cfg.n_heads, cfg.head_dim,
+                              cfg.qk_rope_head_dim, cfg.kv_lora_rank)
         else:
-            blk["mlp"] = mlp_init(ks[1], d, cfg.d_ff, self.mlp_kind)
+            attn = A.attn_init(ks[0], d, cfg.n_heads, cfg.n_kv_heads,
+                               cfg.head_dim, qkv_bias=cfg.qkv_bias)
+        blk = {"norm1": L.rmsnorm_init(d), "attn": attn,
+               "norm2": L.rmsnorm_init(d)}
+        if kind == "moe_attn":
+            blk["moe"] = MOE.moe_init(
+                ks[1], d, cfg.d_ff, cfg.n_experts, cfg.n_shared_experts,
+                self.mlp_kind, n_held=cfg.n_held,
+                correction_bias=cfg.router_score == "sigmoid")
+        else:
+            d_ff = cfg.dense_d_ff if cfg.family == "moe" else cfg.d_ff
+            blk["mlp"] = mlp_init(ks[1], d, d_ff, self.mlp_kind)
         return blk
 
     def act_param_leaves(self) -> Dict[str, Tuple[str, ...]]:
@@ -167,7 +177,8 @@ class LM:
         if cfg.family == "ssm":
             return ("ssd",) * cfg.n_layers
         if cfg.family == "moe":
-            return ("moe_attn",) * cfg.n_layers
+            n = cfg.n_dense_layers
+            return ("attn",) * n + ("moe_attn",) * (cfg.n_layers - n)
         if cfg.family == "hybrid":
             return cfg._pattern()
         return ("attn",) * cfg.n_layers
@@ -200,9 +211,13 @@ class LM:
             params["tail"] = [self._block_init(tkeys[i], kind)
                               for i, kind in enumerate(tail)]
         else:
+            n_lead = cfg.n_dense_layers
             lkeys = jax.random.split(k_layers, cfg.n_layers)
+            if n_lead:
+                params["lead"] = [self._block_init(lkeys[i], kinds[i])
+                                  for i in range(n_lead)]
             params["layers"] = jax.vmap(
-                lambda k: self._block_init(k, kinds[0]))(lkeys)
+                lambda k: self._block_init(k, kinds[-1]))(lkeys[n_lead:])
         return params
 
     # ------------------------------------------------------------------
@@ -235,10 +250,21 @@ class LM:
         window = cfg.window if (cfg.family == "hybrid" and kind == "attn") \
             else 0
         h = self._sp_full(L.rmsnorm_apply(p["norm1"], x, cfg.norm_eps))
-        x = x + self._sp_shard(A.self_attention(
-            p["attn"], h, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-            head_dim=cfg.head_dim, rope_theta=cfg.rope_theta, window=window,
-            positions=positions, kv_chunk=self.kv_chunk, unroll=self.unroll))
+        if cfg.kv_lora_rank:
+            with jax.named_scope(otrace.SCOPE_MLA):
+                y = A.mla_attention(
+                    p["attn"], h, n_heads=cfg.n_heads,
+                    head_dim=cfg.head_dim, rank=cfg.kv_lora_rank,
+                    rope_theta=cfg.rope_theta, eps=cfg.norm_eps,
+                    positions=positions, kv_chunk=self.kv_chunk,
+                    unroll=self.unroll)
+        else:
+            y = A.self_attention(
+                p["attn"], h, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
+                head_dim=cfg.head_dim, rope_theta=cfg.rope_theta,
+                window=window, positions=positions, kv_chunk=self.kv_chunk,
+                unroll=self.unroll)
+        x = x + self._sp_shard(y)
         h = self._sp_full(L.rmsnorm_apply(p["norm2"], x, cfg.norm_eps))
         if kind == "moe_attn":
             moe_fn = MOE.moe_apply
@@ -248,7 +274,9 @@ class LM:
                 p["moe"], h, top_k=cfg.top_k,
                 capacity_factor=cfg.capacity_factor, act=self.act,
                 router_score=cfg.router_score, router_act=self.sigmoid_act,
-                key=key, return_aux=collect_aux)
+                key=key, return_aux=collect_aux,
+                expert_offset=cfg.expert_offset,
+                routed_scaling=cfg.routed_scaling_factor)
             if collect_aux:
                 out, aux = out
             x = x + out
@@ -327,8 +355,13 @@ class LM:
                                            collect_aux=collect_aux)
                 total_aux += aux
         else:
-            kind = self.layer_kinds()[0]
-            x, aux = scan_blocks(x, params["layers"], (kind,))
+            kinds = self.layer_kinds()
+            for p_lead, kind in zip(params.get("lead", []), kinds):
+                x, aux = self._apply_block(p_lead, x, kind,
+                                           positions=positions, key=key,
+                                           collect_aux=collect_aux)
+                total_aux += aux
+            x, aux = scan_blocks(x, params["layers"], (kinds[-1],))
             total_aux += aux
 
         logits = self.logits(params, x)
@@ -379,6 +412,10 @@ class LM:
             return RG.rglru_init_state(batch, cfg.lru_width or cfg.d_model,
                                        cfg.conv_width,
                                        dtype=self.compute_dtype)
+        if cfg.kv_lora_rank:
+            return A.init_mla_cache(
+                batch, max_len, cfg.kv_lora_rank + cfg.qk_rope_head_dim,
+                dtype=self.compute_dtype)
         window = cfg.window if (cfg.family == "hybrid" and kind == "attn") \
             else 0
         return A.init_cache(batch, max_len, cfg.n_kv_heads, cfg.head_dim,
@@ -404,13 +441,25 @@ class LM:
             state["tail"] = [self._block_cache(kind, batch, max_len)
                              for kind in kinds[n_groups * len(pat):]]
         else:
-            one = self._block_cache(kinds[0], batch, max_len)
-            n = cfg.n_layers
+            n_lead = cfg.n_dense_layers
+            if n_lead:
+                state["lead"] = [self._block_cache(kind, batch, max_len)
+                                 for kind in kinds[:n_lead]]
+            one = self._block_cache(kinds[-1], batch, max_len)
+            n = cfg.n_layers - n_lead
             state["layers"] = jax.tree.map(
                 lambda x: jnp.broadcast_to(x, (n,) + x.shape).copy(), one)
+        if cfg.family == "moe":
+            # the last step's (token, expert) pairs per row: routed, and
+            # routed to a held expert (summed over layers); the engine
+            # reads them with the step's tokens
+            state["moe_pairs"] = jnp.zeros((batch, 2), jnp.int32)
         return state
 
-    def _decode_block(self, p, cache_l, x, kind: str, index, *, key=None):
+    def _decode_block(self, p, cache_l, x, kind: str, index, *, key=None,
+                      commit=None):
+        """-> (x, new cache, the MoE layer's per-row pairs or None).
+        ``commit``: as in :meth:`decode_step` (MLA layers only)."""
         cfg = self.cfg
         if kind == "ssd":
             h = L.rmsnorm_apply(p["norm"], x, cfg.norm_eps)
@@ -418,7 +467,7 @@ class LM:
                 p["ssd"], h, cache_l, expand=cfg.ssm_expand,
                 headdim=cfg.ssm_headdim, d_state=cfg.ssm_state,
                 dt_act=self.softplus_act, gate_act=self.silu_act, key=key)
-            return x + y, new
+            return x + y, new, None
         if kind == "rec":
             h = L.rmsnorm_apply(p["norm1"], x, cfg.norm_eps)
             y, new = RG.rglru_decode(p["rec"], h, cache_l, self.sigmoid_act,
@@ -426,32 +475,51 @@ class LM:
             x = x + y
             h = L.rmsnorm_apply(p["norm2"], x, cfg.norm_eps)
             x = x + mlp_apply(p["mlp"], h, self.mlp_kind, self.act, key=key)
-            return x, new
+            return x, new, None
         window = cfg.window if (cfg.family == "hybrid" and kind == "attn") \
             else 0
         h = L.rmsnorm_apply(p["norm1"], x, cfg.norm_eps)
-        y, new = A.decode_self_attention(
-            p["attn"], h, cache_l, index, n_heads=cfg.n_heads,
-            n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
-            rope_theta=cfg.rope_theta, window=window,
-            analog_backend=cfg.analog.backend)
+        if cfg.kv_lora_rank:
+            with jax.named_scope(otrace.SCOPE_MLA):
+                y, new = A.mla_decode(
+                    p["attn"], h, cache_l, index, n_heads=cfg.n_heads,
+                    head_dim=cfg.head_dim, rank=cfg.kv_lora_rank,
+                    rope_theta=cfg.rope_theta, eps=cfg.norm_eps,
+                    analog_backend=cfg.analog.backend, commit=commit)
+        else:
+            y, new = A.decode_self_attention(
+                p["attn"], h, cache_l, index, n_heads=cfg.n_heads,
+                n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
+                rope_theta=cfg.rope_theta, window=window,
+                analog_backend=cfg.analog.backend)
         x = x + y
         h = L.rmsnorm_apply(p["norm2"], x, cfg.norm_eps)
         if kind == "moe_attn":
-            moe_fn = MOE.moe_apply
-            if cfg.moe_impl == "ep_shardmap":
-                from repro.dist.ep import moe_apply_ep as moe_fn
-            x = x + moe_fn(
-                p["moe"], h, top_k=cfg.top_k,
-                capacity_factor=max(cfg.capacity_factor, 2.0),
-                act=self.act, router_score=cfg.router_score,
-                router_act=self.sigmoid_act, key=key)
-        else:
-            x = x + mlp_apply(p["mlp"], h, self.mlp_kind, self.act, key=key)
-        return x, new
+            out, pairs = MOE.moe_decode(
+                p["moe"], h, top_k=cfg.top_k, act=self.act,
+                router_score=cfg.router_score, router_act=self.sigmoid_act,
+                key=key, expert_offset=cfg.expert_offset,
+                routed_scaling=cfg.routed_scaling_factor)
+            return x + out, new, pairs
+        x = x + mlp_apply(p["mlp"], h, self.mlp_kind, self.act, key=key)
+        return x, new, None
 
-    def decode_step(self, params, state: Dict, tokens, *, key=None):
-        """One decode step. tokens: (B, 1) -> (logits (B, 1, V), new state)."""
+    @property
+    def commits_rows(self) -> bool:
+        """Latent-attention models take a masked prefill's per-row commit
+        mask into :meth:`decode_step`; the others are row-selected after
+        each step (``nn.model.prefill_cache``)."""
+        return bool(self.cfg.kv_lora_rank)
+
+    def decode_step(self, params, state: Dict, tokens, *, key=None,
+                    commit=None):
+        """One decode step. tokens: (B, 1) -> (logits (B, 1, V), new state).
+
+        ``commit`` (B,) bool, for models that ``commits_rows``: rows that
+        do not commit leave their latent caches as a fresh state holds them
+        (zeros at the step's position; ``nn.attention.mla_decode``) and
+        their pair counts as they were.  Masked prefill passes it, so that
+        no step copies the whole cache to select rows."""
         cfg = self.cfg
         index = state["index"]
         x = self.embed(params, tokens)
@@ -463,7 +531,7 @@ class LM:
                 new_cl = {}
                 for i, kind in enumerate(pat):
                     name = f"b{i}_{kind}"
-                    x, new_cl[name] = self._decode_block(
+                    x, new_cl[name], _ = self._decode_block(
                         lp[name], cl[name], x, kind, index, key=key)
                 return x, new_cl
 
@@ -475,21 +543,36 @@ class LM:
             n_scanned = (cfg.n_layers // len(pat)) * len(pat)
             for p_tail, c_tail, kind in zip(params["tail"], state["tail"],
                                             kinds[n_scanned:]):
-                x, new_c = self._decode_block(p_tail, c_tail, x, kind, index,
-                                              key=key)
+                x, new_c, _ = self._decode_block(p_tail, c_tail, x, kind,
+                                                 index, key=key)
                 new_state["tail"].append(new_c)
         else:
-            kind = self.layer_kinds()[0]
+            kinds = self.layer_kinds()
+            new_state = {"index": index + 1}
+            if "lead" in params:
+                new_state["lead"] = []
+                for p_lead, c_lead, kind in zip(params["lead"],
+                                                state["lead"], kinds):
+                    x, new_c, _ = self._decode_block(p_lead, c_lead, x, kind,
+                                                     index, key=key,
+                                                     commit=commit)
+                    new_state["lead"].append(new_c)
 
             def body(x, lp_cache):
                 lp, cl = lp_cache
-                x, new_cl = self._decode_block(lp, cl, x, kind, index,
-                                               key=key)
-                return x, new_cl
+                x, new_cl, pairs = self._decode_block(lp, cl, x, kinds[-1],
+                                                      index, key=key,
+                                                      commit=commit)
+                return x, (new_cl, pairs)
 
-            x, new_layers = self._maybe_scan(
+            x, (new_state["layers"], pairs) = self._maybe_scan(
                 body, x, (params["layers"], state["layers"]))
-            new_state = {"index": index + 1, "layers": new_layers}
+            if pairs is not None:
+                pairs = jnp.sum(pairs, axis=0)
+                if commit is not None:
+                    pairs = jnp.where(commit[:, None], pairs,
+                                      state["moe_pairs"])
+                new_state["moe_pairs"] = pairs
         logits = self.logits(params, x)
         return logits, new_state
 

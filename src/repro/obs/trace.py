@@ -32,6 +32,11 @@ name and takes the profiler name ``PROFILE_NAMES`` gives it;
 :func:`annotate` and :func:`annotate_step` open the profiler-only
 sub-spans, which leave no JSONL entry.
 
+Counter log.  :func:`count` adds to named counters of a registry and
+logs the process-wide totals on the ``time.perf_counter`` clock, so that
+:func:`counter_totals` reads them at a past instant (the MoE pair
+counters, ``MOE_PAIRS_*``).
+
 Compile counter.  The first :func:`watch_compiles` registers one
 ``jax.monitoring`` listener per process, which fires only when JAX
 traces, lowers or compiles a program (or reads one from the persistent
@@ -67,6 +72,19 @@ DECODE_SYNC = "serve.decode.sync"              # waiting for the tokens
 DECODE_BOOKKEEP = "serve.decode.bookkeep"      # per-row host work
 SERVE_WARMUP = "serve.warmup"        # ServingEngine.warmup()
 SERVE_COMPILE = "serve.compile"      # lowering and compiling one program
+
+# -- named scopes inside jitted programs --------------------------------
+# ``jax.named_scope`` names: the device ops a profiler trace shows carry
+# them in their op names (PERF.md section 3).
+SCOPE_MLA = "mla_attention"              # latent attention, all of it
+SCOPE_ROUTER = "moe_router"              # router logits, scores, top-k
+SCOPE_HELD_EXPERTS = "moe_held_experts"  # the held routed experts' FFN
+SCOPE_SHARED_EXPERTS = "moe_shared_experts"
+SCOPE_COMBINE = "moe_combine"            # routed outputs weighed and summed
+
+# -- engine counters read on the host clock -------------------------------
+MOE_PAIRS_ROUTED = "moe.pairs_routed"    # (token, expert) pairs routed
+MOE_PAIRS_LOCAL = "moe.pairs_local"      # ... of them to a held expert
 
 # JSONL span name -> profiler name (others keep their own name)
 PROFILE_NAMES = {"admit": SERVE_ADMIT, "prefill": SERVE_PREFILL,
@@ -325,3 +343,40 @@ def compile_totals(at: Optional[float] = None
     if i == 0:
         return None
     return log[i - 1][1], log[i - 1][2]
+
+
+# -- counter log ----------------------------------------------------------
+
+_COUNTS_LOCK = threading.Lock()
+_COUNTS: Dict[str, float] = {}
+_COUNT_LOG: Dict[str, collections.deque] = {}
+
+
+def count(registry, **increments) -> None:
+    """Add each increment (``**{name: n}``) to the counter of that name in
+    ``registry`` and to the process-wide totals, logged on the
+    ``time.perf_counter`` clock."""
+    now = time.perf_counter()
+    with _COUNTS_LOCK:
+        for name, n in increments.items():
+            registry.counter(name).inc(n)
+            total = _COUNTS[name] = _COUNTS.get(name, 0.0) + n
+            log = _COUNT_LOG.setdefault(
+                name, collections.deque([(0.0, 0.0)], maxlen=65536))
+            log.append((now, total))
+
+
+def counter_totals(name: str, at: Optional[float] = None
+                   ) -> Optional[float]:
+    """Process-wide total of counter ``name`` (as :func:`count` adds to
+    it) up to the ``time.perf_counter`` instant ``at`` (default now); None
+    when nothing was ever counted under that name, or when ``at`` lies
+    before the oldest entry the log still holds."""
+    with _COUNTS_LOCK:
+        log = list(_COUNT_LOG.get(name, ()))
+    if not log:
+        return None
+    if at is None:
+        return log[-1][1]
+    i = bisect.bisect_right([t for t, _ in log], at)
+    return None if i == 0 else log[i - 1][1]

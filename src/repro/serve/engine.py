@@ -132,9 +132,11 @@ class _DetokWorker:
             target=self._loop, name="serve-detok", daemon=True)
         self._thread.start()
 
-    def put(self, next_tok, snapshot) -> None:
-        """Enqueue one decode step's device tokens + active-slot snapshot."""
-        self._q.put((next_tok, snapshot))
+    def put(self, next_tok, snapshot, pairs=None, on_pairs=None) -> None:
+        """Enqueue one decode step's device tokens + active-slot snapshot
+        (and a MoE model's per-row pair counts, handed to ``on_pairs``
+        with the active slots once they land)."""
+        self._q.put((next_tok, snapshot, pairs, on_pairs))
 
     def _loop(self) -> None:
         while True:
@@ -142,8 +144,10 @@ class _DetokWorker:
             if item is None:
                 self._q.task_done()
                 return
-            next_tok, snapshot = item
+            next_tok, snapshot, pairs, on_pairs = item
             toks = np.asarray(next_tok)            # device -> host, here
+            if pairs is not None:
+                on_pairs(np.asarray(pairs), [slot for slot, _ in snapshot])
             out = {}
             for slot, req in snapshot:
                 if getattr(req, "_eos_seen", False):
@@ -902,8 +906,14 @@ class ServingEngine:
         with otrace.annotate(otrace.DECODE_DISPATCH):
             next_tok, self.state = self._jit_decode(
                 self.params, self.state, tokens, positions, key)
+        pairs = self.state.get("moe_pairs")
         with otrace.annotate(otrace.DECODE_SYNC):
-            next_np = np.asarray(next_tok)
+            if pairs is None:
+                next_np = np.asarray(next_tok)
+            else:
+                next_np, pairs = jax.device_get((next_tok, pairs))
+        if pairs is not None:
+            self._count_pairs(pairs, active)
         with otrace.annotate(otrace.DECODE_BOOKKEEP):
             out = {}
             for s in active:
@@ -947,7 +957,8 @@ class ServingEngine:
             self._slot_last_dev = jnp.where(jnp.asarray(mask), next_tok,
                                             self._slot_last_dev)
             self._detok.put(next_tok,
-                            [(s, self.slot_req[s]) for s in active])
+                            [(s, self.slot_req[s]) for s in active],
+                            self.state.get("moe_pairs"), self._count_pairs)
             for s in active:
                 uid = self.slot_req[s].uid
                 self.slot_pos[s] += 1
@@ -962,6 +973,15 @@ class ServingEngine:
                     self.slot_free[s] = True
                     self.slot_req[s] = None
         return self._drain_detok()
+
+    def _count_pairs(self, pairs, rows) -> None:
+        """A MoE decode step's (token, expert) pairs over its real rows:
+        ``moe.pairs_routed`` and ``moe.pairs_local`` (those routed to an
+        expert held here), summed over layers."""
+        routed, local = np.asarray(pairs)[rows].sum(axis=0)
+        otrace.count(self.obs.metrics, **{
+            otrace.MOE_PAIRS_ROUTED: int(routed),
+            otrace.MOE_PAIRS_LOCAL: int(local)})
 
     def _note_token(self, s: int, uid: int) -> None:
         """Per-token obs bookkeeping at DISPATCH time (identical in the

@@ -87,7 +87,7 @@ def test_full_config_numbers_match_assignment(arch):
         "granite-34b": (88, 6144, 48, 1, 24576, 49152),
         "granite-3-8b": (40, 4096, 32, 8, 12800, 49155),
         "qwen2.5-3b": (36, 2048, 16, 2, 11008, 151936),
-        "moonshot-v1-16b-a3b": (48, 2048, 16, 16, 1408, 163840),
+        "moonlight-16b-a3b": (27, 2048, 16, 16, 1408, 163840),
         "deepseek-moe-16b": (28, 2048, 16, 16, 1408, 102400),
         "recurrentgemma-9b": (38, 4096, 16, 1, 12288, 256000),
         "mamba2-370m": (48, 1024, 0, 0, 0, 50280),
@@ -95,8 +95,12 @@ def test_full_config_numbers_match_assignment(arch):
     got = (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
            cfg.d_ff, cfg.vocab)
     assert got == expected, (got, expected)
-    if arch in ("moonshot-v1-16b-a3b", "deepseek-moe-16b"):
+    if arch in ("moonlight-16b-a3b", "deepseek-moe-16b"):
         assert cfg.n_experts == 64 and cfg.top_k == 6
+    if arch == "moonlight-16b-a3b":
+        assert (cfg.kv_lora_rank, cfg.qk_rope_head_dim) == (512, 64)
+        assert (cfg.n_dense_layers, cfg.dense_d_ff) == (1, 11264)
+        assert cfg.routed_scaling_factor == 2.446
     if arch == "mamba2-370m":
         assert cfg.ssm_state == 128
     if arch == "recurrentgemma-9b":

@@ -18,7 +18,7 @@ N_PARAMS_PIN = {
     "granite-34b": (47_248_834_560, 47_248_834_560),
     "granite-3-8b": (8_172_601_344, 8_172_601_344),
     "qwen2.5-3b": (3_085_959_168, 3_085_959_168),
-    "moonshot-v1-16b-a3b": (28_888_268_800, 4_804_575_232),
+    "moonlight-16b-a3b": (15_960_110_208, 2_914_776_192),
     "deepseek-moe-16b": (16_879_452_160, 2_830_630_912),
     "recurrentgemma-9b": (10_007_822_336, 10_007_822_336),
     "mamba2-370m": (355_467_264, 355_467_264),
@@ -33,6 +33,14 @@ def test_n_params_pinned(arch):
     want_total, want_active = N_PARAMS_PIN[arch]
     assert cfg.n_params() == want_total, arch
     assert cfg.n_active_params() == want_active, arch
+
+
+def test_moonlight_counts_match_published_and_the_chips_share():
+    """The whole model is the published 15.96B; one chip of the EP8
+    deployment holds 8 of the 64 routed experts of each MoE layer."""
+    cfg = configs.get("moonlight-16b-a3b")
+    assert cfg.n_params() == 15_960_110_208
+    assert cfg.replace(experts_held=8).n_params() == 3_364_615_296
 
 
 def test_moe_active_below_total():

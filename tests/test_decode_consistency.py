@@ -11,7 +11,7 @@ from repro.nn.frontends import audio_frame_stub
 from repro.nn.model import build
 
 FAMS = ["qwen2.5-3b", "granite-34b", "mamba2-370m", "recurrentgemma-9b",
-        "moonshot-v1-16b-a3b", "whisper-base"]
+        "moonlight-16b-a3b", "whisper-base"]
 
 
 @pytest.mark.parametrize("arch", FAMS)

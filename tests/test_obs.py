@@ -596,3 +596,117 @@ def test_trace_deterministic_across_resume(backend, tmp_path):
     assert part["trace"] + resumed["trace"] == full["trace"]
     assert resumed["tokens"] == full["tokens"]
     assert resumed["ttft"] == full["ttft"]
+
+
+# ---------------------------------------------------------------------------
+# MoE pair counters and the named scopes of the MLA + MoE block
+# ---------------------------------------------------------------------------
+
+
+def _moonlight_smoke():
+    cfg = configs.get_smoke("moonlight-16b-a3b").replace(
+        dtype="float32", experts_held=2, expert_offset=3,
+        analog=AnalogSpec(enabled=False))
+    model = build(cfg)
+    return cfg, model, model.init(jax.random.PRNGKey(0))
+
+
+def test_moe_pair_counters_match_a_host_recount(monkeypatch):
+    """``moe.pairs_routed`` and ``moe.pairs_local``, over the real rows of
+    each decode step and summed over layers, equal a recount of the
+    router's choices on the host: a callback hands each decode call's
+    routing to the test, which counts the rows the step decoded."""
+    from repro.nn import moe as MOE
+    from repro.obs import trace as otrace
+
+    cfg, model, params = _moonlight_smoke()
+    held = range(cfg.expert_offset, cfg.expert_offset + cfg.experts_held)
+    seen = {"rows": None, "routed": 0, "local": 0, "calls": 0}
+    route = MOE.route
+
+    def spy(p, xf, **kw):
+        out = route(p, xf, **kw)
+
+        def note(idx):
+            if seen["rows"] is not None and idx.shape[0] == 3:
+                idx = np.asarray(idx)[seen["rows"]]
+                seen["calls"] += 1
+                seen["routed"] += idx.size
+                seen["local"] += int(np.isin(idx, list(held)).sum())
+        jax.debug.callback(note, out[1])
+        return out
+
+    step_sync = ServingEngine._step_sync
+
+    def step(self, active):
+        seen["rows"] = list(active)
+        try:
+            return step_sync(self, active)
+        finally:
+            seen["rows"] = None
+
+    monkeypatch.setattr(MOE, "route", spy)
+    monkeypatch.setattr(ServingEngine, "_step_sync", step)
+    eng = ServingEngine(model, params, max_batch=3, max_len=32)
+    rng = np.random.default_rng(5)
+    for uid, (n, new) in enumerate(((5, 6), (9, 3))):
+        eng.submit(Request(uid=uid, prompt=rng.integers(0, cfg.vocab, n)
+                           .astype(np.int32), max_new_tokens=new))
+    before = otrace.counter_totals(otrace.MOE_PAIRS_LOCAL) or 0.0
+    assert eng.run_to_completion() == 9
+    routed = eng.obs.metrics.find(otrace.MOE_PAIRS_ROUTED).value
+    local = eng.obs.metrics.find(otrace.MOE_PAIRS_LOCAL).value
+    n_moe = cfg.n_layers - cfg.n_dense_layers
+    assert seen["calls"] == 6 * n_moe                   # decode steps x layers
+    assert routed == seen["routed"] == 9 * n_moe * cfg.top_k      # 9 tokens
+    assert local == seen["local"] and 0 < local < routed
+    assert otrace.counter_totals(otrace.MOE_PAIRS_LOCAL) == before + local
+
+
+def test_moe_pair_counters_same_with_detok_thread():
+    """The detokenize thread counts the pairs when its batch lands: the
+    same totals as the synchronous path."""
+    from repro.obs import trace as otrace
+
+    cfg, model, params = _moonlight_smoke()
+    got = []
+    for detok in (False, True):
+        eng = ServingEngine(model, params, max_batch=3, max_len=32,
+                            detok_thread=detok)
+        rng = np.random.default_rng(6)
+        for uid, n in enumerate((4, 7, 6)):
+            eng.submit(Request(uid=uid, prompt=rng.integers(0, cfg.vocab, n)
+                               .astype(np.int32), max_new_tokens=5))
+        eng.run_to_completion()
+        eng.detok_flush()
+        got.append(tuple(eng.obs.metrics.find(name).value for name in
+                         (otrace.MOE_PAIRS_ROUTED, otrace.MOE_PAIRS_LOCAL)))
+    n_moe = cfg.n_layers - cfg.n_dense_layers
+    assert got[0] == got[1] and got[0][0] == 15 * n_moe * cfg.top_k
+
+
+def test_dense_model_counts_no_pairs(smoke_model):
+    cfg, model, params = smoke_model
+    eng = ServingEngine(model, params, max_batch=2, max_len=32)
+    eng.submit(Request(uid=0, prompt=np.arange(5, dtype=np.int32),
+                       max_new_tokens=3))
+    eng.run_to_completion()
+    assert "moe_pairs" not in eng.state
+    assert eng.obs.metrics.find("moe.pairs_routed") is None
+    assert eng.obs.metrics.find("moe.pairs_local") is None
+
+
+def test_mla_and_moe_scopes_name_device_ops():
+    """Every named scope of the block reaches the compiled decode step's
+    op names, which a profiler trace shows."""
+    from repro.obs import trace as otrace
+
+    cfg, model, params = _moonlight_smoke()
+    state = model.init_decode_state(2, 16)
+    tokens = np.zeros((2, 1), np.int32)
+    text = jax.jit(model.decode_step).lower(params, state, tokens) \
+        .compile().as_text()
+    for scope in (otrace.SCOPE_MLA, otrace.SCOPE_ROUTER,
+                  otrace.SCOPE_HELD_EXPERTS, otrace.SCOPE_SHARED_EXPERTS,
+                  otrace.SCOPE_COMBINE):
+        assert f"/{scope}/" in text, scope

@@ -1,4 +1,5 @@
-"""The main-path Pallas kernels compile for a TPU v5e at qwen2.5-3b widths.
+"""The main-path Pallas kernels compile for a TPU v5e at qwen2.5-3b widths,
+and Moonlight-16B-A3B's latent attention, experts and router at its own.
 
 Nothing runs: each kernel is lowered through its ``repro.kernels.ops``
 wrapper and compiled by the TPU compiler for a described (not attached)
@@ -30,6 +31,9 @@ DECODE_ROWS = 4                 # a small decode batch
 # the skinny block plan, 4096 (prefill, training) the 256-row default
 MATMUL_ROWS = [4, 32, 4096]
 BITS = 5
+# Moonlight-16B-A3B: 16 heads over a 512 + 64 latent cache; 8 held experts
+# of width 1408 at the benchmark engine's 64 rows; a 64-expert router
+MLA_HEADS, MLA_RANK, MLA_WIDTH, MOE_ROWS = 16, 512, 576, 64
 
 
 @pytest.fixture(scope="module")
@@ -155,3 +159,29 @@ def test_lstm_gates(compiled):
     sig, tanh = build_ramp("sigmoid", BITS), build_ramp("tanh", BITS)
     compiled(lambda g, c: ops.lstm_gates(g, c, sig, tanh),
              ((256, 4 * 512), jnp.float32), ((256, 512), jnp.float32))
+
+
+@pytest.mark.parametrize("s_len", [768, 4096])
+def test_mla_decode_attention(compiled, s_len):
+    """Latent attention over the position-minor cache, as every MLA decode
+    step calls it."""
+    compiled(lambda q, c, m: ops.mla_decode_attention(
+                 q, c, m, rank=MLA_RANK, scale=192 ** -0.5),
+             ((MOE_ROWS, MLA_HEADS, MLA_WIDTH), jnp.bfloat16),
+             ((MOE_ROWS, MLA_WIDTH, s_len), jnp.bfloat16),
+             ((MOE_ROWS, s_len), jnp.bool_))
+
+
+def test_moe_expert_gate(compiled):
+    """The held experts' gate: the fused matmul vmapped over 8 experts, each
+    taking every row of the decode call."""
+    ramp = _ramp()
+    compiled(lambda x, w: ops.moe_fused_matmul(x, w, ramp),
+             ((8, MOE_ROWS, D_MODEL), jnp.bfloat16),
+             ((8, D_MODEL, 1408), jnp.bfloat16))
+
+
+def test_router_sigmoid(compiled):
+    """The router's NL-ADC sigmoid on float32 logits over 64 experts."""
+    ramp = build_ramp("sigmoid", BITS)
+    compiled(lambda x: ops.nladc(x, ramp), ((MOE_ROWS, 64), jnp.float32))
