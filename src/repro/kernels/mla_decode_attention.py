@@ -1,7 +1,7 @@
 """Pallas kernel: one-query attention against a latent (MLA) decode cache.
 
 Latent attention caches, per position, one ``rank + rope_dim`` wide row
-``[c, k_r]`` shared by every head (``nn.attention.mla_decode``).  With
+``[c, k_r]`` shared by every head (``nn.attention.mla_decode_attend``).  With
 ``W_UK`` absorbed into the query and ``W_UV`` into the output, a decode
 step's attention is multi-query attention over that cache: scores over
 all ``rank + rope_dim`` features, values over the first ``rank``.  The

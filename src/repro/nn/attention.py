@@ -377,6 +377,23 @@ def init_mla_cache(batch: int, max_len: int, width: int,
     return {"ckv": jnp.zeros((batch, width, max_len), dtype)}
 
 
+def mla_cache_write(ckv, entry, index, layer=None):
+    """Write one position's entry (B, W, 1) at ``index`` of a latent cache
+    -> (cache, the layer's (B, W, S) view of it).
+
+    ``layer`` None: ``ckv`` is one layer's (B, W, S).  Else it is the
+    stacked (L, B, W, S) cache of a layer scan, carried through the scan:
+    the entry lands at (``layer``, 0, 0, ``index``) in place, and only the
+    layer's view is read out for the attention."""
+    entry = entry.astype(ckv.dtype)
+    if layer is None:
+        ckv = jax.lax.dynamic_update_slice_in_dim(ckv, entry, index, axis=2)
+        return ckv, ckv
+    ckv = jax.lax.dynamic_update_slice(ckv, entry[None],
+                                       (layer, 0, 0, index))
+    return ckv, jax.lax.dynamic_index_in_dim(ckv, layer, keepdims=False)
+
+
 def mla_attend(q, ckv, mask, *, rank: int, scale: float):
     """One-query attention over a latent cache (the absorbed form).
 
@@ -396,38 +413,49 @@ def mla_attend(q, ckv, mask, *, rank: int, scale: float):
     return o.astype(q.dtype)
 
 
-def mla_decode(p, x, cache, index, *, n_heads, head_dim, rank, rope_theta,
-               eps, analog_backend: str = "", commit=None):
-    """One-token MLA decode step at position ``index`` -> (y, new_cache).
+def mla_decode_query(p, x, index, *, n_heads, head_dim, rank, rope_theta,
+                     eps, commit=None):
+    """One token's MLA decode inputs at position ``index`` -> (q, entry).
 
-    ``commit`` (B,) bool, from a masked prefill: rows that do not commit
-    write zeros at ``index``, which is what the fresh state it fills holds
-    there, so no step selects rows over the whole cache.  (Reading each
-    row's old entry back instead would make the compiler relayout the
-    whole position-minor cache at every step.)"""
-    from repro.core import backend as BK
-
-    b = x.shape[0]
+    q (B, H, rank + rope_dim): the query with W_UK absorbed, [q_c, q_r];
+    entry (B, rank + rope_dim, 1): the token's [c, k_r], which the caller
+    writes at ``index`` of its latent cache before
+    :func:`mla_decode_attend` reads it.  ``commit`` (B,) bool, from a
+    masked prefill: rows that do not commit get a zero entry, which is
+    what the fresh state it fills holds there, so no step selects rows
+    over the whole cache.  (Reading each row's old entry back instead
+    would make the compiler relayout the whole position-minor cache at
+    every step.)"""
     pos = jnp.full((1, 1), index, dtype=jnp.int32)
     q_nope, q_r = _mla_query(p, x, pos, n_heads, head_dim, rope_theta)
-    new = _mla_latent(p, x, pos, rank, rope_theta, eps)
-    entry = jnp.swapaxes(new, 1, 2).astype(cache["ckv"].dtype)
+    entry = jnp.swapaxes(_mla_latent(p, x, pos, rank, rope_theta, eps), 1, 2)
     if commit is not None:
         entry = jnp.where(commit[:, None, None], entry, 0)
-    ckv = jax.lax.dynamic_update_slice_in_dim(cache["ckv"], entry, index,
-                                              axis=2)
     w = p["kv_b_proj"]["w"].reshape(rank, n_heads, 2 * head_dim) \
         .astype(x.dtype)
     q_c = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], w[..., :head_dim])
-    q = jnp.concatenate([q_c, q_r[:, 0]], axis=-1).astype(ckv.dtype)
+    return jnp.concatenate([q_c, q_r[:, 0]], axis=-1), entry
+
+
+def mla_decode_attend(p, q, ckv, index, *, n_heads, head_dim, rank,
+                      analog_backend: str = ""):
+    """One-query MLA over a layer's latent cache ``ckv`` (B, rank +
+    rope_dim, S), which holds positions 0..``index`` -> y (B, 1, d_model)
+    in q's dtype.  The attention is the backend's
+    ``mla_decode_attention`` (the Pallas kernel, or :func:`mla_attend`);
+    W_UV is applied after it."""
+    from repro.core import backend as BK
+
+    b, dtype = q.shape[0], q.dtype
+    w = p["kv_b_proj"]["w"].reshape(rank, n_heads, 2 * head_dim) \
+        .astype(dtype)
     mask = jnp.broadcast_to(jnp.arange(ckv.shape[2]) <= index,
                             (b, ckv.shape[2]))
     o_c = BK.get_backend(analog_backend).mla_decode_attention(
-        q, ckv, mask, rank=rank,
-        scale=mla_scale(head_dim, q_r.shape[-1]))
-    o = jnp.einsum("bhr,rhd->bhd", o_c.astype(x.dtype), w[..., head_dim:])
-    y = L.dense_apply(p["o_proj"], o.reshape(b, 1, n_heads * head_dim))
-    return y, {"ckv": ckv}
+        q.astype(ckv.dtype), ckv, mask, rank=rank,
+        scale=mla_scale(head_dim, q.shape[-1] - rank))
+    o = jnp.einsum("bhr,rhd->bhd", o_c.astype(dtype), w[..., head_dim:])
+    return L.dense_apply(p["o_proj"], o.reshape(b, 1, n_heads * head_dim))
 
 
 # ---------------------------------------------------------------------------

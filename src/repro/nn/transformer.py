@@ -457,9 +457,11 @@ class LM:
         return state
 
     def _decode_block(self, p, cache_l, x, kind: str, index, *, key=None,
-                      commit=None):
+                      commit=None, layer=None):
         """-> (x, new cache, the MoE layer's per-row pairs or None).
-        ``commit``: as in :meth:`decode_step` (MLA layers only)."""
+        ``commit``: as in :meth:`decode_step` (MLA layers only).
+        ``layer``: an MLA layer's index in the stacked latent cache that
+        ``cache_l`` then holds (``nn.attention.mla_cache_write``)."""
         cfg = self.cfg
         if kind == "ssd":
             h = L.rmsnorm_apply(p["norm"], x, cfg.norm_eps)
@@ -481,11 +483,18 @@ class LM:
         h = L.rmsnorm_apply(p["norm1"], x, cfg.norm_eps)
         if cfg.kv_lora_rank:
             with jax.named_scope(otrace.SCOPE_MLA):
-                y, new = A.mla_decode(
-                    p["attn"], h, cache_l, index, n_heads=cfg.n_heads,
+                q, entry = A.mla_decode_query(
+                    p["attn"], h, index, n_heads=cfg.n_heads,
                     head_dim=cfg.head_dim, rank=cfg.kv_lora_rank,
                     rope_theta=cfg.rope_theta, eps=cfg.norm_eps,
-                    analog_backend=cfg.analog.backend, commit=commit)
+                    commit=commit)
+                ckv, view = A.mla_cache_write(cache_l["ckv"], entry, index,
+                                              layer)
+                new = {"ckv": ckv}
+                y = A.mla_decode_attend(
+                    p["attn"], q, view, index, n_heads=cfg.n_heads,
+                    head_dim=cfg.head_dim, rank=cfg.kv_lora_rank,
+                    analog_backend=cfg.analog.backend)
         else:
             y, new = A.decode_self_attention(
                 p["attn"], h, cache_l, index, n_heads=cfg.n_heads,
@@ -511,15 +520,29 @@ class LM:
         each step (``nn.model.prefill_cache``)."""
         return bool(self.cfg.kv_lora_rank)
 
+    @property
+    def decode_in_place(self) -> bool:
+        """:meth:`decode_step` writes every cache of its state in place
+        (the layer scan carries the stacked latent cache), so a caller
+        that donates the state saves a copy.  Where the scan returns a
+        cache as its ys, a donated state costs a copy of the whole stack
+        into the donated buffer at every step."""
+        return bool(self.cfg.kv_lora_rank)
+
     def decode_step(self, params, state: Dict, tokens, *, key=None,
                     commit=None):
         """One decode step. tokens: (B, 1) -> (logits (B, 1, V), new state).
 
         ``commit`` (B,) bool, for models that ``commits_rows``: rows that
         do not commit leave their latent caches as a fresh state holds them
-        (zeros at the step's position; ``nn.attention.mla_decode``) and
-        their pair counts as they were.  Masked prefill passes it, so that
-        no step copies the whole cache to select rows."""
+        (zeros at the step's position; ``nn.attention.mla_decode_query``)
+        and their pair counts as they were.  Masked prefill passes it, so
+        that no step copies the whole cache to select rows.
+
+        A stacked latent cache rides the layer scan as part of its carry,
+        each layer writing its new position into it in place
+        (``nn.attention.mla_cache_write``); the other caches are the
+        scan's xs and ys."""
         cfg = self.cfg
         index = state["index"]
         x = self.embed(params, tokens)
@@ -558,15 +581,30 @@ class LM:
                                                      commit=commit)
                     new_state["lead"].append(new_c)
 
-            def body(x, lp_cache):
-                lp, cl = lp_cache
-                x, new_cl, pairs = self._decode_block(lp, cl, x, kinds[-1],
-                                                      index, key=key,
-                                                      commit=commit)
-                return x, (new_cl, pairs)
+            if "ckv" in state["layers"]:
+                # as xs and ys, each layer's cache would be copied out of
+                # the stack and written back whole to add one position
 
-            x, (new_state["layers"], pairs) = self._maybe_scan(
-                body, x, (params["layers"], state["layers"]))
+                def body(carry, lp_layer):
+                    (x, cache), (lp, layer) = carry, lp_layer
+                    x, cache, pairs = self._decode_block(
+                        lp, cache, x, kinds[-1], index, key=key,
+                        commit=commit, layer=layer)
+                    return (x, cache), pairs
+
+                n = state["layers"]["ckv"].shape[0]
+                (x, new_state["layers"]), pairs = self._maybe_scan(
+                    body, (x, state["layers"]),
+                    (params["layers"], jnp.arange(n, dtype=jnp.int32)))
+            else:
+                def body(x, lp_cache):
+                    lp, cl = lp_cache
+                    x, new_cl, pairs = self._decode_block(
+                        lp, cl, x, kinds[-1], index, key=key, commit=commit)
+                    return x, (new_cl, pairs)
+
+                x, (new_state["layers"], pairs) = self._maybe_scan(
+                    body, x, (params["layers"], state["layers"]))
             if pairs is not None:
                 pairs = jnp.sum(pairs, axis=0)
                 if commit is not None:

@@ -282,6 +282,7 @@ class ServingEngine:
         self.max_batch = max_batch
         self.max_len = max_len
         self.state = model.init_decode_state(max_batch, max_len)
+        self._donate_state = bool(getattr(model, "decode_in_place", False))
         # Infer-mode models draw per-read noise (the device model's
         # ReadNoise stage) every decode/prefill step; the engine owns the
         # key schedule so serving is reproducible for a given noise_seed.
@@ -378,7 +379,12 @@ class ServingEngine:
         move the host-side thresholds ahead of the still-compiled step, and
         a checkpoint must record what is being served, not what is pending.
         """
-        self._jit_decode = jax.jit(self._decode_all)
+        # a model that writes its decode state in place gets it donated:
+        # the step's new state takes over its buffers, so every caller
+        # rebinds ``self.state`` to the result and keeps no other reference
+        self._jit_decode = jax.jit(
+            self._decode_all,
+            donate_argnums=(1,) if self._donate_state else ())
         self._jit_prefill = jax.jit(self._prefill_slot,
                                     static_argnames=("length",))
         self._prefill_exec.clear()
@@ -500,18 +506,21 @@ class ServingEngine:
                 self._scatter_rows(self._pack_template(), [])
             # one representative-shape decode call triggers (and caches)
             # the jit compile; the result is discarded and no engine
-            # state — in particular the noise-key schedule — advances.
-            # Waiting for it leaves nothing of the warm-up queued on the
-            # device, holding memory, when traffic starts.
+            # state — in particular the noise-key schedule — advances (a
+            # donated state is given as a copy).  Waiting for it leaves
+            # nothing of the warm-up queued on the device, holding memory,
+            # when traffic starts.
             tokens = jnp.zeros((self.max_batch, 1), jnp.int32)
             positions = jnp.zeros((self.max_batch,), jnp.int32)
             key = None
             if self._noisy:
                 key = self._noise_key
                 jax.random.split(key)     # the key schedule's own program
+            state = jax.tree.map(jnp.copy, self.state) \
+                if self._donate_state else self.state
             with otrace.annotate(otrace.SERVE_COMPILE):
                 jax.block_until_ready(self._jit_decode(
-                    self.params, self.state, tokens, positions, key))
+                    self.params, state, tokens, positions, key))
         return out
 
     # -- threshold fingerprints (bucket-aware invalidation) ------------

@@ -14,6 +14,7 @@ whose two sides quantize the same numbers.
 
 import dataclasses
 import os
+import re
 import sys
 
 import jax
@@ -26,6 +27,7 @@ from repro.configs.base import AnalogSpec
 from repro.nn import attention as A
 from repro.nn import moe as MOE
 from repro.nn.model import build
+from repro.serve.engine import ServingEngine
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
@@ -153,6 +155,76 @@ def test_mla_kernel_matches_the_jnp_path(dtype):
     want = A.mla_attend(q, ckv, mask, rank=rank, scale=0.125)
     assert got.shape == (b, h, rank) and got.dtype == dtype
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _dus_updates(text, shape):
+    """The update shapes of every dynamic-update-slice into a buffer of
+    ``shape`` in compiled HLO text (fusion bodies included)."""
+    dims = dict(re.findall(r"%(\S+) = \w+\[([\d,]*)\]", text))
+    into = r"= \w+\[" + ",".join(map(str, shape)) \
+        + r"\]\S* dynamic-update-slice\(%[^,]+, %([^,]+),"
+    return [tuple(int(d) for d in dims[u].split(","))
+            for u in re.findall(into, text)]
+
+
+@pytest.mark.parametrize("program", ["decode", "masked_prefill"])
+def test_latent_stack_takes_one_position_per_layer(program):
+    """The stacked latent cache rides the layer scan as a carry: each layer
+    writes its (B, W, 1) entry into the stack, and no op writes a whole
+    (B, W, S) layer back (the decode step, and the masked prefill that
+    scans it)."""
+    cfg = smoke_cfg()
+    model = build(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    b, s = 4, 32
+    state = model.init_decode_state(b, s)
+    stack = state["layers"]["ckv"].shape
+    _, _, w, _ = stack
+    if program == "decode":
+        lowered = jax.jit(model.decode_step).lower(
+            params, state, np.zeros((b, 1), np.int32))
+    else:
+        lowered = jax.jit(model.prefill_cache).lower(
+            params, state, np.zeros((b, 8), np.int32),
+            np.full((b,), 8, np.int32))
+    updates = _dus_updates(lowered.compile().as_text(), stack)
+    assert updates and set(updates) == {(1, b, w, 1)}, updates
+
+
+@pytest.mark.parametrize("index", [0, 17, 39])
+@pytest.mark.parametrize("stacked", [False, True])
+def test_latent_cache_write_is_the_one_position_write(index, stacked):
+    """The cache comes back with only the entry written at ``index`` (of
+    layer 1 of a stack, or of one layer's cache), every other position
+    and layer as it was; the view returned is the layer's."""
+    rng = np.random.default_rng(index)
+    cache = jnp.asarray(rng.standard_normal((3, 2, 5, 40)), jnp.bfloat16)
+    entry = jnp.asarray(rng.standard_normal((2, 5, 1)), jnp.float32)
+    layer = 1 if stacked else None
+    ckv = cache if stacked else cache[1]
+    got, view = jax.jit(A.mla_cache_write)(ckv, entry, index, layer)
+    want = cache.at[1, :, :, index].set(entry[..., 0].astype(jnp.bfloat16))
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(want if stacked else want[1]))
+    np.testing.assert_array_equal(np.asarray(view), np.asarray(want[1]))
+
+
+def test_engine_decode_aliases_the_latent_stack():
+    """The engine donates the decode state: the compiled decode program
+    writes the stacked latent cache's output over its input."""
+    cfg = smoke_cfg()
+    model = build(cfg)
+    eng = ServingEngine(model, model.init(jax.random.PRNGKey(0)),
+                        max_batch=4, max_len=32)
+    text = eng._jit_decode.lower(
+        eng.params, eng.state, jnp.zeros((4, 1), jnp.int32),
+        jnp.zeros((4,), jnp.int32), None).compile().as_text()
+    (param,) = re.findall(
+        r"parameter\((\d+)\), metadata=\{op_name=\"state\[\\'layers\\'\]"
+        r"\[\\'ckv\\'\]", text)
+    header = text.split("\n", 1)[0]
+    assert re.search(r"input_output_alias=\{[^\n]*: \(" + param + r", \{\}",
+                     header), header[:300]
 
 
 def _moe_layer(cfg, rc, params, share):
