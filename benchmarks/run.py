@@ -30,7 +30,6 @@ MODULES = [
     ("bank_sweep", "threshold-bank sweep (INL/accuracy vs col-tile count)"),
     ("recal_schedule", "serving-lifetime re-calibration schedule sweep"),
     ("fleet_sweep", "fleet serving sweep (N chips x capacity floor)"),
-    ("serve_throughput", "offline serving: scan vs bucketed AOT prefill"),
     ("kernel_bench", "kernel microbench"),
     ("kernel_tune", "per-shape kernel block autotune sweep + digests"),
     ("backend_parity", "ref-vs-pallas backend parity + throughput"),

@@ -145,8 +145,7 @@ def serve_phase(cfg, *, seed: int = 0) -> dict:
          f"{time.perf_counter() - t0:.1f} s")
 
     engine = ServingEngine(model, params, max_batch=MAX_BATCH,
-                           max_len=MAX_LEN, prefill="bucketed",
-                           prefill_buckets=PREFILL_BUCKETS)
+                           max_len=MAX_LEN, prefill_buckets=PREFILL_BUCKETS)
     t0 = time.perf_counter()
     engine.warmup()
     _log(f"compile seconds, pallas engine (decode step + prefill buckets "

@@ -3,11 +3,11 @@
 Spins up the batched serving engine, submits a wave of synthetic requests,
 and reports tokens/s + per-request outputs.
 
-Throughput knobs: ``--prefill-buckets`` (AOT-compiled power-of-two prefill
-buckets; 'auto' or an explicit list), ``--pack-prefill`` (one padded
-prefill call per admission wave), ``--detok-thread`` (background
-detokenize pipeline), and ``--offline`` (MLPerf-offline style: ``warmup()``
-pre-compiles everything, then one measured burst).
+Throughput knobs: ``--prefill-buckets`` (the AOT-compiled prefill lengths
+each admission wave rounds up to; 'auto', the default, or an explicit
+list), ``--detok-thread`` (background detokenize pipeline), and
+``--offline`` (MLPerf-offline style: ``warmup()`` pre-compiles everything,
+then one measured burst).
 
 Device-lifecycle knobs (``--age-per-step-s`` / ``--recal-every`` /
 ``--recal-inl-lsb``) attach a :class:`repro.serve.lifecycle.RecalScheduler`
@@ -60,15 +60,10 @@ def main():
                     help="threshold banks: output columns per NL-ADC ramp "
                          "(one ramp per crossbar col-tile; 0 = one shared "
                          "ramp per activation, the legacy layout)")
-    ap.add_argument("--prefill-buckets", default="",
-                    help="throughput path: comma-separated AOT prefill "
-                         "bucket lengths (e.g. '8,16,32') or 'auto' for "
-                         "powers of two up to max_len-1; empty = legacy "
-                         "per-length scan prefill")
-    ap.add_argument("--pack-prefill", action="store_true",
-                    help="pack a whole admission wave of short prompts "
-                         "into one padded bucket call (requires "
-                         "--prefill-buckets)")
+    ap.add_argument("--prefill-buckets", default="auto",
+                    help="comma-separated AOT prefill bucket lengths "
+                         "(e.g. '8,16,32') or 'auto' for powers of two up "
+                         "to max_len-1")
     ap.add_argument("--detok-thread", action="store_true",
                     help="background detokenize/backlog thread: host "
                          "transfer + bookkeeping overlap the next device "
@@ -150,19 +145,14 @@ def main():
     except (ValueError, OSError) as e:
         ap.error(f"--kernel-blocks/--kernel-cache: {e}")
 
-    if args.pack_prefill and not args.prefill_buckets:
-        ap.error("--pack-prefill requires --prefill-buckets")
     prefill_kw = {"detok_thread": args.detok_thread}
-    if args.prefill_buckets:
-        prefill_kw["prefill"] = "bucketed"
-        prefill_kw["pack_prefill"] = args.pack_prefill
-        if args.prefill_buckets != "auto":
-            try:
-                prefill_kw["prefill_buckets"] = tuple(
-                    int(b) for b in args.prefill_buckets.split(","))
-            except ValueError:
-                ap.error("--prefill-buckets must be 'auto' or a "
-                         "comma-separated list of ints")
+    if args.prefill_buckets != "auto":
+        try:
+            prefill_kw["prefill_buckets"] = tuple(
+                int(b) for b in args.prefill_buckets.split(","))
+        except ValueError:
+            ap.error("--prefill-buckets must be 'auto' or a "
+                     "comma-separated list of ints")
 
     cfg = configs.get_smoke(args.arch) if args.smoke \
         else configs.get(args.arch)

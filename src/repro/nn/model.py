@@ -74,10 +74,10 @@ def prefill_cache(model, params, state, tokens, valid_len, *, key=None,
     int32 — each row's TOTAL prefill length (global, so chunked calls keep
     passing the same value).  Rows commit a step's update only while
     ``state["index"] + t < valid_len``; masked steps compute and discard
-    (pure), so the committed leaves are **bitwise** what a row-at-a-time
-    scan over ``decode_step`` would have written — exact by construction,
-    including rolling windows and int8 KV layouts, because it IS the decode
-    path.  Chunking falls out of the shared ``index``: call again with the
+    (pure), so the committed leaves are what a row-at-a-time scan over
+    ``decode_step`` would have written — exact by construction, including
+    rolling windows and int8 KV layouts, because it IS the decode path (up
+    to the summation order of contractions batched over the rows).  Chunking falls out of the shared ``index``: call again with the
     next ``B`` columns and the scan resumes at the global position.
 
     A model that ``commits_rows`` (latent attention) takes the mask into

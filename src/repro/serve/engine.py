@@ -10,28 +10,19 @@ Production shape (vLLM-style, sized down to JAX-native primitives):
   batching); finished slots (EOS / max_tokens) free immediately;
 * per-slot position offsets let requests of different lengths coexist.
 
-Two prefill paths share one correctness anchor (bitwise-identical token
-streams and decode caches, tested on both backends, noisy and noiseless):
+Admission has one path.  Each wave's prompts are packed into the rows of
+one ``(max_batch, bucket)`` call: the wave's longest prefill rounds up to
+a **prefill length bucket**, and each bucket is an **AOT-compiled
+executable** (``jax.jit(...).lower(...).compile()``) built once and reused
+for every wave that rounds up into it; ``warmup()`` pre-compiles every
+bucket and the decode step before traffic arrives.  Each row is masked to
+its own length, and the resulting caches **scatter** into their batch
+slots.  Prompts longer than the largest bucket run **chunked**: repeated
+largest-bucket calls carrying the state, the shared ``index`` keeping
+cache positions and the noise-key schedule global.
 
-* ``prefill="scan"`` (default) — the legacy path: per-request jitted
-  ``lax.scan`` over ``decode_step`` (exact w.r.t. the cache layout,
-  including rolling windows), one compile per distinct prompt length.
-* ``prefill="bucketed"`` — the MLPerf-offline-style throughput path:
-  **power-of-two prefill length buckets**, each an **AOT-compiled
-  executable** (``jax.jit(...).lower(...).compile()``) built once and
-  reused for every prompt that rounds up into the bucket; ``warmup()``
-  pre-compiles every bucket and the decode step before traffic arrives.
-  With ``pack_prefill=True`` one padded prefill call carries the whole
-  admission wave (several short prompts batched into the pack rows, each
-  masked to its own length) and the resulting caches **scatter** into
-  their batch slots — generalizing the single-slot ``_merge_slot``.
-  Prompts longer than the largest bucket run **chunked**: repeated
-  largest-bucket calls carrying the state, the shared ``index`` keeping
-  cache positions and the noise-key schedule global.
-
-Attention inside both paths dispatches through the kernel layer: each
-``decode_step`` (and therefore every prefill position, since prefill is a
-masked scan of decode steps) attends over the cache via
+Attention inside prefill dispatches through the kernel layer: prefill is
+a masked scan of ``decode_step``, and each step attends over the cache via
 ``backend.prefill_attention`` — the Pallas cached-attention kernel under
 ``REPRO_ANALOG_BACKEND=pallas``, ``attend_full`` on the ref backend —
 with block sizes resolved per shape from the :mod:`repro.kernels.tune`
@@ -226,6 +217,13 @@ class ServingEngine:
     which stops admission and lets the standard drain point apply the
     re-program.  This is how a fleet staggers maintenance windows so
     capacity never drops below its floor.
+
+    ``prefill_buckets``: the prefill lengths compiled, strictly increasing
+    (None: :meth:`_default_buckets` of ``max_len``).
+
+    ``prefill`` and ``pack_prefill`` accept only ``"bucketed"`` and
+    ``True``, the one admission path; any other value raises.  They stay
+    only until the benchmark's workload files stop passing them.
     """
 
     SCHEMA = 2          # checkpoint schema this build writes/understands
@@ -234,21 +232,19 @@ class ServingEngine:
                  device=None, noise_seed: int = 0, recal=None,
                  drain_before_rejit: bool = False,
                  external_maintenance: bool = False,
-                 prefill: str = "scan",
                  prefill_buckets=None,
-                 pack_prefill: bool = False,
                  detok_thread: bool = False,
-                 obs=None):
+                 obs=None,
+                 prefill: str = "bucketed",
+                 pack_prefill: bool = True):
         from repro.obs import ChipEnergyModel, EnergyMeter, Obs
         from repro.serve.lifecycle import RecalScheduler, analog_activations
 
-        if prefill not in ("scan", "bucketed"):
+        if prefill != "bucketed" or pack_prefill is not True:
             raise ValueError(
-                f"prefill must be 'scan' or 'bucketed', got {prefill!r}")
-        if prefill != "bucketed" and (pack_prefill
-                                      or prefill_buckets is not None):
-            raise ValueError(
-                "pack_prefill / prefill_buckets require prefill='bucketed'")
+                "ServingEngine has one prefill path, packed and bucketed: "
+                "prefill must be 'bucketed' and pack_prefill True, got "
+                f"prefill={prefill!r}, pack_prefill={pack_prefill!r}")
 
         self.device = device
         self._pristine_params = params
@@ -298,22 +294,16 @@ class ServingEngine:
         self.slot_pos = np.zeros(max_batch, np.int32)     # next position
         self.slot_last = np.zeros(max_batch, np.int32)    # last token
         self.queue: List[Request] = []
-        # -- throughput path: bucketed AOT prefill / packing / detokenize --
-        self.prefill_mode = prefill
-        self.pack_prefill = bool(pack_prefill)
-        self._pack_rows = max_batch if pack_prefill else 1
-        if prefill == "bucketed":
-            buckets = tuple(int(b) for b in (
-                prefill_buckets if prefill_buckets is not None
-                else self._default_buckets(max_len)))
-            if not buckets or any(b <= 0 for b in buckets) \
-                    or list(buckets) != sorted(set(buckets)):
-                raise ValueError(
-                    f"prefill_buckets must be strictly increasing positive "
-                    f"lengths, got {buckets}")
-            self.prefill_buckets: tuple = buckets
-        else:
-            self.prefill_buckets = ()
+        # -- admission: bucketed AOT prefill, packed waves ------------------
+        buckets = tuple(int(b) for b in (
+            prefill_buckets if prefill_buckets is not None
+            else self._default_buckets(max_len)))
+        if not buckets or any(b <= 0 for b in buckets) \
+                or list(buckets) != sorted(set(buckets)):
+            raise ValueError(
+                f"prefill_buckets must be strictly increasing positive "
+                f"lengths, got {buckets}")
+        self.prefill_buckets: tuple = buckets
         self._prefill_exec: Dict[int, object] = {}   # bucket -> executable
         self._exec_fp: Dict[int, tuple] = {}         # bucket -> thresholds
         self._batch_axes_cache = None
@@ -385,8 +375,6 @@ class ServingEngine:
         self._jit_decode = jax.jit(
             self._decode_all,
             donate_argnums=(1,) if self._donate_state else ())
-        self._jit_prefill = jax.jit(self._prefill_slot,
-                                    static_argnames=("length",))
         self._prefill_exec.clear()
         self._exec_fp.clear()
         self._served_ramps = {name: np.asarray(act.ramp.thresholds).copy()
@@ -443,12 +431,12 @@ class ServingEngine:
         return self._batch_axes_cache
 
     def _pack_template(self):
-        """The fresh (all-zero) pack-rows decode state every prefill wave
-        starts from.  Never mutated (executables return new arrays), so
-        one allocation serves the engine's lifetime."""
+        """The fresh (all-zero) ``max_batch``-row decode state every prefill
+        wave starts from.  Never mutated (executables return new arrays),
+        so one allocation serves the engine's lifetime."""
         if self._pack_tmpl is None:
             self._pack_tmpl = self.model.init_decode_state(
-                self._pack_rows, self.max_len)
+                self.max_batch, self.max_len)
         return self._pack_tmpl
 
     def _prefill_packed(self, params, state, tokens, valid_len, key):
@@ -478,7 +466,7 @@ class ServingEngine:
             self._m_bucket_hit.inc()
             return ex
         self._m_bucket_compile.inc()
-        P = self._pack_rows
+        P = self.max_batch
         tokens = jnp.zeros((P, bucket), jnp.int32)
         vlen = jnp.zeros((P,), jnp.int32)
         key = self._noise_key if self._noisy else None
@@ -501,9 +489,8 @@ class ServingEngine:
             for b in self.prefill_buckets:
                 self._ensure_prefill_exec(b)
                 out["prefill_buckets"].append(b)
-            if self.prefill_buckets:
-                # a scatter of no rows: compiles its ops, moves nothing
-                self._scatter_rows(self._pack_template(), [])
+            # a scatter of no rows: compiles its ops, moves nothing
+            self._scatter_rows(self._pack_template(), [])
             # one representative-shape decode call triggers (and caches)
             # the jit compile; the result is discarded and no engine
             # state — in particular the noise-key schedule — advances (a
@@ -539,9 +526,9 @@ class ServingEngine:
         return tuple(fp)
 
     def _served_fp(self) -> tuple:
-        """The fingerprint the *currently compiled* decode/legacy-prefill
-        traces serve (their snapshot, not the host-side activations —
-        during a drain window the two differ)."""
+        """The fingerprint the *currently compiled* decode trace serves
+        (its snapshot, not the host-side activations — during a drain
+        window the two differ)."""
         banks_all = self._served_bank_state()
         fp = []
         for name in sorted(self._acts):
@@ -556,10 +543,9 @@ class ServingEngine:
         """Bucket-aware re-jit after a chip re-program.
 
         Drops (and eagerly re-AOTs) only the bucket executables whose
-        traced thresholds actually moved, and keeps the decode /
-        legacy-prefill traces when no threshold did — a weight-only
-        re-program passes params as runtime arguments, so its traces
-        still serve the current chip.  A recal storm therefore no longer
+        traced thresholds actually moved, and keeps the decode trace when
+        no threshold did — a weight-only re-program passes params as
+        runtime arguments, so its traces still serve the current chip.  A recal storm therefore no longer
         throws away every compiled prefill.  What happened lands in
         ``last_invalidation`` (the fleet surfaces it on
         ``reprogram_done`` events).
@@ -615,29 +601,6 @@ class ServingEngine:
         next_tok = jnp.argmax(logits[:, -1, :self.model.cfg.vocab],
                               axis=-1).astype(jnp.int32)
         return next_tok, new_state
-
-    def _prefill_slot(self, params, state, tokens, key, *, length: int):
-        """Feed a prompt through decode steps to fill the cache (exact).
-
-        Per-step noise keys fold the admission wave's key at the absolute
-        prompt position (``fold_in(key, t)``) — length-independent, and
-        the same schedule the bucketed/packed executables derive from the
-        global index, which is what makes the two prefill paths bitwise
-        interchangeable under noise.
-        """
-
-        def body(st, inp):
-            t, tok = inp
-            k = None if key is None else jax.random.fold_in(key, t)
-            _, st = self.model.decode_step(params, st, tok[None, None],
-                                           key=k)
-            return st, None
-
-        # note: fills batch slot 0 of a broadcast state; engine embeds the
-        # single-request state into the big batch after (host-side gather).
-        state, _ = jax.lax.scan(
-            body, state, (jnp.arange(length), tokens[:length]))
-        return state
 
     # -- host-side scheduling -------------------------------------------
 
@@ -703,14 +666,19 @@ class ServingEngine:
         }
 
     def _admit(self):
-        """Prefill queued requests into free slots.
+        """Prefill queued requests into free slots: one packed wave.
+
+        The wave's prompts fill the rows of one ``max_batch``-row state,
+        each masked to its own length.  Its longest prefill rounds up to a
+        compiled bucket; a prompt longer than every bucket runs as repeated
+        largest-bucket calls carrying the state.  The rows then scatter
+        into their batch slots.
 
         One noise key per admission wave (drawn iff any admitted prompt
         actually prefills), shared by every request admitted together —
-        all reads of one wave see the same physical chip instance, and
-        noise draws are weight-/threshold-shaped, never batch-shaped, so
-        the scan, bucketed, and packed paths consume the key schedule
-        identically (the parity anchor).
+        all reads of one wave see the same physical chip instance — and
+        folded in at each global prompt position, so the schedule does not
+        depend on the chunking.
         """
         if self._rejit_pending:
             # draining toward a planned re-jit: no new admissions — they
@@ -727,34 +695,44 @@ class ServingEngine:
         wave_key = self._next_key() if any(len(r.prompt) > 1
                                            for _, r in admits) else None
         # energy: every crossbar macro fires once per cached prompt
-        # position (padding in the bucketed path excluded — documented
-        # as useful-position accounting in repro.obs.energy)
-        self.energy.add_processed(sum(max(len(r.prompt) - 1, 0)
-                                      for _, r in admits))
+        # position (bucket padding excluded — documented as useful-
+        # position accounting in repro.obs.energy)
+        lens = [len(req.prompt) - 1 for _, req in admits]
+        self.energy.add_processed(sum(max(n, 0) for n in lens))
         with self.obs.span("admit", n=len(admits)):
-            if self.prefill_mode == "bucketed":
-                self._admit_bucketed(admits, wave_key)
-                return
+            state = self._pack_template()
+            l_max = max(lens)
+            if l_max > 0:
+                P = self.max_batch
+                toks = np.zeros((P, l_max), np.int32)
+                vlen = np.zeros((P,), np.int32)
+                for row, (_, req) in enumerate(admits):
+                    toks[row, :lens[row]] = np.asarray(
+                        req.prompt[:lens[row]], np.int32)
+                    vlen[row] = lens[row]
+                vlen_j = jnp.asarray(vlen)
+                pos, sp_buckets = 0, []
+                with self.obs.span("prefill", rows=len(admits),
+                                   max_len=int(l_max)) as sp:
+                    while pos < l_max:
+                        bucket = self._bucket_for(l_max - pos)
+                        ex = self._ensure_prefill_exec(bucket)
+                        sp_buckets.append(bucket)
+                        chunk = np.zeros((P, bucket), np.int32)
+                        width = min(bucket, l_max - pos)
+                        chunk[:, :width] = toks[:, pos:pos + width]
+                        # the state's shared index carries the global
+                        # position between chunks (cache writes and the
+                        # fold_in key schedule both key off it)
+                        state = ex(self.params, state, jnp.asarray(chunk),
+                                   vlen_j, wave_key)
+                        pos += bucket
+                    sp.set(buckets=sp_buckets)
             for slot, req in admits:
-                with self.obs.span("prefill", slot=slot,
-                                   length=int(len(req.prompt) - 1)):
-                    mini_state = self.model.init_decode_state(
-                        1, self.max_len)
-                    mini_state = self._fill(mini_state, req.prompt,
-                                            wave_key)
                 self._bookkeep_admit(slot, req)
-                with otrace.annotate(otrace.SERVE_SCATTER):
-                    self._merge_slot(mini_state, slot)
-
-    def _fill(self, state, prompt, wave_key):
-        # Jitted scan over the prompt (minus the last token, which decodes
-        # in the shared batch step).  One compile per distinct prompt
-        # length; the bucketed path exists precisely to amortize that.
-        if len(prompt) <= 1:
-            return state
-        tokens = jnp.asarray(np.asarray(prompt), jnp.int32)
-        return self._jit_prefill(self.params, state, tokens, wave_key,
-                                 length=len(prompt) - 1)
+            with otrace.annotate(otrace.SERVE_SCATTER):
+                self._scatter_rows(state, [(row, slot) for row, (slot, _)
+                                           in enumerate(admits)])
 
     def _bookkeep_admit(self, slot: int, req: Request):
         wait = self._step_ord - self._submit_ord.get(req.uid,
@@ -776,59 +754,14 @@ class ServingEngine:
         if self._detok is not None:
             self._slot_last_dev = self._slot_last_dev.at[slot].set(tok)
 
-    def _admit_bucketed(self, admits, wave_key):
-        """Bucketed/packed admission: round the wave's longest prefill up
-        to a compiled bucket, run the whole wave through that executable
-        (packed: all rows in one call; unpacked: one row-call each), chunk
-        with repeated largest-bucket calls when the prompt is longer than
-        every bucket, then scatter the resulting cache rows into their
-        batch slots."""
-        groups = [admits] if self.pack_prefill else [[a] for a in admits]
-        P = self._pack_rows
-        for group in groups:
-            lens = [len(req.prompt) - 1 for _, req in group]
-            state = self._pack_template()
-            l_max = max(lens)
-            sp_buckets = []
-            if l_max > 0:
-                toks = np.zeros((P, l_max), np.int32)
-                vlen = np.zeros((P,), np.int32)
-                for row, (_, req) in enumerate(group):
-                    toks[row, :lens[row]] = np.asarray(
-                        req.prompt[:lens[row]], np.int32)
-                    vlen[row] = lens[row]
-                vlen_j = jnp.asarray(vlen)
-                pos = 0
-                with self.obs.span("prefill", rows=len(group),
-                                   max_len=int(l_max)) as sp:
-                    while pos < l_max:
-                        bucket = self._bucket_for(l_max - pos)
-                        ex = self._ensure_prefill_exec(bucket)
-                        sp_buckets.append(bucket)
-                        chunk = np.zeros((P, bucket), np.int32)
-                        width = min(bucket, l_max - pos)
-                        chunk[:, :width] = toks[:, pos:pos + width]
-                        # the state's shared index carries the global
-                        # position between chunks (cache writes and the
-                        # fold_in key schedule both key off it)
-                        state = ex(self.params, state, jnp.asarray(chunk),
-                                   vlen_j, wave_key)
-                        pos += bucket
-                    sp.set(buckets=sp_buckets)
-            for row, (slot, req) in enumerate(group):
-                self._bookkeep_admit(slot, req)
-            with otrace.annotate(otrace.SERVE_SCATTER):
-                self._scatter_rows(state, [(row, slot) for row, (slot, _)
-                                           in enumerate(group)])
-
     def _scatter_rows(self, mini, assign):
-        """Scatter pack rows into their batch slots (generalizing the
-        single-slot :meth:`_merge_slot` to a whole admission wave): per
-        leaf, gather the assigned rows along the batch axis and commit
-        them only at the assigned slots — exact copies, untouched slots
-        keep their in-flight state bit-for-bit.  The shared index becomes
-        the max over the assigned slots' positions, as in
-        :meth:`_merge_slot`."""
+        """Scatter pack rows into their batch slots: per leaf, gather the
+        assigned rows along the batch axis and commit them only at the
+        assigned slots — exact copies, untouched slots keep their
+        in-flight state bit-for-bit.  The shared index becomes the max of
+        itself and the assigned slots' positions (per-slot positions are
+        tracked host-side; one shared index is exact when slots admit in
+        waves)."""
         perm = np.zeros(self.max_batch, np.int64)
         mask = np.zeros(self.max_batch, bool)
         for row, slot in assign:
@@ -851,27 +784,6 @@ class ServingEngine:
         top = max((self.slot_pos[slot] for _, slot in assign), default=0)
         self.state["index"] = jnp.maximum(self.state["index"],
                                           jnp.asarray(np.int32(top)))
-
-    def _merge_slot(self, mini_state, slot):
-        """Copy the single-request cache into batch slot ``slot``."""
-
-        def merge(big, small):
-            if big.ndim == 0:
-                return big
-            # find the batch dim: mini has size 1 where big has max_batch
-            for ax in range(big.ndim):
-                if small.shape[ax] == 1 and big.shape[ax] == self.max_batch:
-                    idx = [slice(None)] * big.ndim
-                    idx[ax] = slice(slot, slot + 1)
-                    return big.at[tuple(idx)].set(small)
-            return big
-
-        self.state = jax.tree.map(merge, self.state, mini_state)
-        # global index = max over active slots; per-slot positions tracked
-        # host-side (single shared index is exact when slots admit in waves;
-        # documented simplification vs. per-slot index plumbing)
-        self.state["index"] = jnp.maximum(
-            self.state["index"], jnp.asarray(self.slot_pos[slot]))
 
     def step(self) -> Dict[int, int]:
         """One engine iteration: admit + decode. Returns {uid: token}.
@@ -1329,9 +1241,7 @@ class ServingEngine:
                 params_like=None,
                 drain_before_rejit: bool = False,
                 external_maintenance: bool = False,
-                prefill: str = "scan",
                 prefill_buckets=None,
-                pack_prefill: bool = False,
                 detok_thread: bool = False,
                 obs=None) -> "ServingEngine":
         """Resume a checkpointed deployment: same chip, same next token.
@@ -1377,8 +1287,7 @@ class ServingEngine:
                   max_len=meta["engine"]["max_len"],
                   drain_before_rejit=drain_before_rejit,
                   external_maintenance=external_maintenance,
-                  prefill=prefill, prefill_buckets=prefill_buckets,
-                  pack_prefill=pack_prefill, detok_thread=detok_thread,
+                  prefill_buckets=prefill_buckets, detok_thread=detok_thread,
                   obs=obs)
         # Realize the checkpointed bank inventory BEFORE building the
         # restore template, so the leaf paths line up with the save — and
@@ -1413,8 +1322,8 @@ class ServingEngine:
         has_sched = meta["scheduler"] is not None
         tree, _, _ = load_checkpoint(
             root, eng._ckpt_tree(include_pristine=has_sched), step=step)
-        # load_checkpoint returns host numpy; the decode state is mutated
-        # with jnp .at[] updates (slot merge) so put it back on device.
+        # load_checkpoint returns host numpy; the decode state is updated
+        # on device (slot scatter), so put it back there.
         eng.params = jax.tree.map(jnp.asarray, tree["params"])
         # without a scheduler nothing re-ages, so the served params stand
         # in for pristine (never read again)
@@ -1428,9 +1337,9 @@ class ServingEngine:
         eng.slot_req = [None if d is None else Request.from_dict(d)
                         for d in meta["requests"]["slots"]]
         eng.queue = [Request.from_dict(d) for d in meta["requests"]["queue"]]
-        # throughput-path mirrors: the checkpoint was flushed at save, so
-        # the host arrays are authoritative (any prefill/detok mode can
-        # resume any checkpoint — the modes share one state layout)
+        # detokenize mirrors: the checkpoint was flushed at save, so the
+        # host arrays are authoritative (an engine with or without the
+        # detokenize thread resumes any checkpoint — one state layout)
         if eng._detok is not None:
             eng._slot_last_dev = jnp.asarray(eng.slot_last, jnp.int32)
         for s, req in enumerate(eng.slot_req):

@@ -236,8 +236,7 @@ class FleetEngine:
     def build(cls, cfg, n_chips: int, *, policy: FleetPolicy = FleetPolicy(),
               recal=None, max_batch: int = 2, max_len: int = 64,
               canary_presets=(), params=None, noise_seed: int = 0,
-              prefill: str = "scan", prefill_buckets=None,
-              pack_prefill: bool = False, detok_thread: bool = False,
+              prefill_buckets=None, detok_thread: bool = False,
               obs=None) -> "FleetEngine":
         """Instantiate a fresh fleet of ``n_chips`` for one model config.
 
@@ -245,9 +244,9 @@ class FleetEngine:
         those device presets; the rest inherit ``cfg.analog.device``.
         ``params`` (pristine, pre-aging) is shared — chips differ by their
         device draws, not their trained weights; default is
-        ``serving_params(model, PRNGKey(0))`` built once.  The throughput knobs
-        (``prefill`` / ``prefill_buckets`` / ``pack_prefill`` /
-        ``detok_thread``) pass through to every chip's engine.
+        ``serving_params(model, PRNGKey(0))`` built once.
+        ``prefill_buckets`` and ``detok_thread`` pass through to every
+        chip's engine.
         """
         if n_chips < 1:
             raise ValueError(f"n_chips must be >= 1, got {n_chips}")
@@ -271,8 +270,7 @@ class FleetEngine:
             chip, params = cls._build_chip(
                 cfg, spec, recal=recal, max_batch=max_batch,
                 max_len=max_len, params=params, noise_seed=noise_seed,
-                prefill=prefill, prefill_buckets=prefill_buckets,
-                pack_prefill=pack_prefill, detok_thread=detok_thread,
+                prefill_buckets=prefill_buckets, detok_thread=detok_thread,
                 obs=obs.child(spec.chip_id))
             chips[spec.chip_id] = chip
         return cls(chips, policy, recal=recal, obs=obs)
@@ -280,8 +278,7 @@ class FleetEngine:
     @staticmethod
     def _build_chip(cfg, spec: ChipSpec, *, recal, max_batch, max_len,
                     params, noise_seed, device_dict=None,
-                    prefill: str = "scan", prefill_buckets=None,
-                    pack_prefill: bool = False, detok_thread: bool = False,
+                    prefill_buckets=None, detok_thread: bool = False,
                     obs=None):
         """Realize one chip (device, model, engine); returns (chip, params)
         with params initialized on first use so the fleet shares one tree.
@@ -317,8 +314,7 @@ class FleetEngine:
             device=dev, recal=recal,
             noise_seed=noise_seed ^ zlib.crc32(spec.chip_id.encode()),
             external_maintenance=True,
-            prefill=prefill, prefill_buckets=prefill_buckets,
-            pack_prefill=pack_prefill, detok_thread=detok_thread,
+            prefill_buckets=prefill_buckets, detok_thread=detok_thread,
             obs=obs)
         return Chip(spec, dev, model, engine), params
 

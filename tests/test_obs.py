@@ -416,8 +416,7 @@ def test_compile_counter_counts_only_new_programs(smoke_model):
     new prompt bucket builds at least one."""
     cfg, model, params = smoke_model
     eng = ServingEngine(model, params, max_batch=2, max_len=48,
-                        prefill="bucketed", prefill_buckets=(8, 16),
-                        pack_prefill=True)
+                        prefill_buckets=(8, 16))
     programs = eng.obs.metrics.find("compile.programs")
     seconds = eng.obs.metrics.find("compile.seconds")
     eng.warmup()

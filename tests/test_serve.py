@@ -195,8 +195,7 @@ def _serve_two_waves(model, params, ckpt_root, *, donated):
     ``donated`` False builds the decode program without donation.
     -> (each wave's streams, the checkpoint's directory)."""
     eng = ServingEngine(model, params, max_batch=4, max_len=32,
-                        prefill="bucketed", prefill_buckets=(8,),
-                        pack_prefill=True)
+                        prefill_buckets=(8,))
     if not donated:
         eng._jit_decode = jax.jit(eng._decode_all)
     eng.warmup()
@@ -242,8 +241,7 @@ def test_donated_decode_state_serves_the_undonated_tokens(arch, tmp_path):
     assert got == want
     assert all(len(t) == 6 for wave in got for t in wave.values())
     eng = ServingEngine.restore(model, str(tmp_path / "after"),
-                                prefill="bucketed", prefill_buckets=(8,),
-                                pack_prefill=True)
+                                prefill_buckets=(8,))
     resumed = [r for r in eng.slot_req if r is not None]
     assert len(resumed) == 4 and ckpt.endswith("step_00000001")
     eng.run_to_completion()
